@@ -28,6 +28,7 @@ from benchmarks import (endurance_sweep, fig2_switching, fig6_thermal,
                         fig15_variation, kernel_bench, prefix_reuse,
                         retention_sweep, serving_energy, shard_scaling,
                         table1, telemetry_overhead, workload_mixes)
+from repro.launch.compile_cache import use_compile_cache
 
 BENCHES = {
     "table1": lambda fast: table1.run(),
@@ -102,7 +103,8 @@ def _headline(name: str, out) -> str:
         return f"approx_spread_lower={out['fig15_claim_approx_spread_lower']}"
     if name == "kernel_bench":
         return (f"fusion_x={out['fusion_traffic_reduction_x']} "
-                f"v5e_us={out['projected_v5e_us_fused']}")
+                f"device={out['device']['kind']} "
+                f"hbm_floor_us={out.get('hbm_floor_us_fused', 'n/a')}")
     if name == "serving_energy":
         k = next(iter(out))
         return (f"{k}: saving={out[k]['saving_vs_basic']:.3f} "
@@ -178,6 +180,7 @@ def _next_bench_path(out_dir: Path) -> Path:
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only")
     ap.add_argument("--fast", action="store_true")

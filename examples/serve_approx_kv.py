@@ -14,9 +14,10 @@ The approximate write is fused into the jitted decode burst (one compiled
 ``lax.scan`` call per decode span, stats accumulated on device, synced
 once per generate/scheduler event). ``--backend`` selects the write-path
 implementation from the ``repro.memory`` registry — "lanes_ref" (default)
-is the pure-jnp lane path, "pallas" the kernel (auto-interpreted on CPU
-hosts: slow, correctness-mode; native on TPU), "oracle" the eager
-bit-unpacked reference.
+is the pure-jnp lane path, "pallas" the kernel (the Pallas interpreter
+off a TPU: slow, correctness-mode; native on a TPU, where chip_smoke.py
+runs it bit-identical to "lanes_ref"), "oracle" the eager bit-unpacked
+reference.
 """
 import argparse
 

@@ -4,6 +4,11 @@ Implements the identical semantics (same murmur3 counter RNG, same bit
 algebra, same stats) with plain jnp ops over the unpacked (elements x nbits)
 tensor — the slow-but-obviously-correct reference every kernel test
 asserts against bit-exactly.
+
+``plane_stats`` is the one place per-bit-plane counts become stats: the
+kernel wrappers (ops.py) and these references both reduce to exact integer
+counts per plane and price them here, so energy agrees bit-for-bit across
+the kernel, the reference and any block partition.
 """
 from __future__ import annotations
 
@@ -24,6 +29,29 @@ def _uniform_bits_all(seed: jax.Array, elem: jax.Array,
     h = (elem.astype(jnp.uint32)[..., None] * _K_ELEM
          ^ (bits * _K_BIT) ^ seed.astype(jnp.uint32))
     return _hash_u32(h)
+
+
+def plane_stats(c01: jax.Array, c10: jax.Array, err: jax.Array,
+                e01: jax.Array, e10: jax.Array) -> Dict[str, jax.Array]:
+    """Stats from (nbits,) int32 per-plane counts of 0->1 flips, 1->0 flips
+    and failures. Energy is priced per plane and summed plane by plane in
+    a fixed order (an unrolled chain, never a reduction whose order the
+    compiler picks), so equal counts give bit-equal energy everywhere."""
+    terms = (c01.astype(jnp.float32) * e01.astype(jnp.float32)
+             + c10.astype(jnp.float32) * e10.astype(jnp.float32))
+    energy = terms[0]
+    for b in range(1, terms.shape[0]):
+        energy = energy + terms[b]
+    return {"energy_pj": energy,
+            "flips01": jnp.sum(c01, dtype=jnp.int32),
+            "flips10": jnp.sum(c10, dtype=jnp.int32),
+            "errors": jnp.sum(err, dtype=jnp.int32)}
+
+
+def plane_counts(rewrite, to_ap, fail):
+    """(R, C, nbits) bool planes -> (nbits,) int32 (0->1, 1->0, failed)."""
+    count = lambda m: jnp.sum(m, axis=(0, 1), dtype=jnp.int32)
+    return count(to_ap), count(rewrite & ~to_ap), count(fail)
 
 
 def extent_write_ref(
@@ -55,11 +83,4 @@ def extent_write_ref(
                         dtype=jnp.uint32)
     stored = new_u32 ^ fail_mask
 
-    e_bit = jnp.where(to_ap, e01, e10)
-    stats = {
-        "energy_pj": jnp.sum(jnp.where(flip, e_bit, 0.0), dtype=jnp.float32),
-        "flips01": jnp.sum(to_ap, dtype=jnp.int32),
-        "flips10": jnp.sum(flip & ~to_ap, dtype=jnp.int32),
-        "errors": jnp.sum(fail, dtype=jnp.int32),
-    }
-    return stored, stats
+    return stored, plane_stats(*plane_counts(flip, to_ap, fail), e01, e10)

@@ -12,21 +12,26 @@ RNG as extent_write) and the int8 pack all happen in VREGs; HBM sees only
 the int8 payload + per-block scales. Unfused, the quantize and the
 approximate-store each round-trip the tensor.
 
-Layout: input (R, C) float lanes; per-row-block scale (grid_r, grid_c).
-Dequant lives in ops.py (one multiply at read time — decode attention
+Layout: input (R, C) float lanes; each grid block writes its scale and its
+failed-bit count as one (8, 128) f32 / i32 tile (the chip stores vectors to
+VMEM, never scalars) and ops.py takes one value per tile, giving
+per-row-block (grid_r, grid_c) arrays. The seed and the per-bit thresholds
+are scalar operands, in SMEM. Dequant lives in ops.py (one multiply at read time — decode attention
 consumes int8 K/V against f32 scales).
 """
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.extent_write.kernel import uniform_bits
+from repro.kernels.extent_write.kernel import STATS_TILE, uniform_bits
+from repro.kernels.platform import resolve_interpret
 
 DEFAULT_BLOCK = (256, 512)
 QMAX = 127.0
@@ -67,8 +72,9 @@ def _kernel(x_ref, seed_ref, thr_ref, stored_ref, scale_ref, errors_ref,
     # sign-extend back to int32 then truncate to int8 semantics
     stored = (stored_u.astype(jnp.int32) ^ 0x80) - 0x80
     stored_ref[...] = stored.astype(jnp.int8)
-    scale_ref[0, 0] = scale
-    errors_ref[0, 0] = jnp.sum(nerr.astype(jnp.int32))
+    scale_ref[...] = jnp.full(STATS_TILE, scale, jnp.float32)
+    errors_ref[...] = jnp.full(STATS_TILE, jnp.sum(nerr.astype(jnp.int32)),
+                               jnp.int32)
 
 
 def kv_quant_kernel(
@@ -77,29 +83,28 @@ def kv_quant_kernel(
     thr: jax.Array,         # (8,) uint32 per-bit failure thresholds
     *,
     block: Tuple[int, int] = DEFAULT_BLOCK,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
-    """Returns (q_int8 (R, C), scales (gr, gc) f32, errors (gr, gc) i32)."""
+    """Returns (q_int8 (R, C), scale tiles (gr * 8, gc * 128) f32, error
+    tiles (gr * 8, gc * 128) i32): every element of a block's (8, 128)
+    tile holds that block's scale / failed-bit count."""
     R, C = x.shape
     assert R % block[0] == 0 and C % block[1] == 0, (x.shape, block)
     grid = (R // block[0], C // block[1])
+    tiles = (grid[0] * STATS_TILE[0], grid[1] * STATS_TILE[1])
+    tile_spec = pl.BlockSpec(STATS_TILE, lambda r, c: (r, c))
+    smem_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
     return pl.pallas_call(
         functools.partial(_kernel, block=block, cols_total=C),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec(block, lambda r, c: (r, c)),
-            pl.BlockSpec((1,), lambda r, c: (0,)),
-            pl.BlockSpec((8,), lambda r, c: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec(block, lambda r, c: (r, c)),
-            pl.BlockSpec((1, 1), lambda r, c: (r, c)),
-            pl.BlockSpec((1, 1), lambda r, c: (r, c)),
-        ],
+        in_specs=[pl.BlockSpec(block, lambda r, c: (r, c)), smem_spec,
+                  smem_spec],
+        out_specs=[pl.BlockSpec(block, lambda r, c: (r, c)), tile_spec,
+                   tile_spec],
         out_shape=[
             jax.ShapeDtypeStruct((R, C), jnp.int8),
-            jax.ShapeDtypeStruct(grid, jnp.float32),
-            jax.ShapeDtypeStruct(grid, jnp.int32),
+            jax.ShapeDtypeStruct(tiles, jnp.float32),
+            jax.ShapeDtypeStruct(tiles, jnp.int32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, seed, thr)

@@ -2,7 +2,7 @@
 from __future__ import annotations
 
 import functools
-from typing import Dict, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -34,7 +34,7 @@ def kv_quant_store(
     level: Priority = Priority.MID,
     block: Tuple[int, int] = (64, 128),
     use_kernel: bool = True,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> Tuple[jax.Array, jax.Array, Dict[str, jax.Array]]:
     """Quantize + approximately store a KV tensor.
 
@@ -58,8 +58,10 @@ def kv_quant_store(
     x2 = xp.reshape(rows, block[1])
     blk = (min(block[0], rows), block[1])
     if use_kernel:
-        q2, scales, errors = K.kv_quant_kernel(x2, seed, thr, block=blk,
-                                               interpret=interpret)
+        q2, scale_tiles, error_tiles = K.kv_quant_kernel(
+            x2, seed, thr, block=blk, interpret=interpret)
+        th, tw = K.STATS_TILE  # one value per block tile
+        scales, errors = scale_tiles[::th, ::tw], error_tiles[::th, ::tw]
     else:
         q2, scales, errors = R.kv_quant_ref(x2, seed, thr, blk)
     q = q2.reshape(-1)[:n].reshape(kv.shape)

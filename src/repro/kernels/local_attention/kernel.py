@@ -24,13 +24,15 @@ Supports gemma2's attention logit softcap (tanh) inside the tile loop.
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.platform import resolve_interpret
 
 NEG_INF = -2.0e38
 
@@ -90,7 +92,7 @@ def local_attention_kernel(
     softcap: float = 0.0,
     bq: int = 256,
     bk: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     BH, S, h = q.shape
     G = num_q_heads // num_kv_heads
@@ -127,5 +129,5 @@ def local_attention_kernel(
             pltpu.VMEM((bq,), jnp.float32),      # l: running denom
             pltpu.VMEM((bq, h), jnp.float32),    # acc: running numerator
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)

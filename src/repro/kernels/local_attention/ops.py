@@ -2,7 +2,7 @@
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -21,7 +21,7 @@ def local_attention(
     softcap: float = 0.0,
     bq: int = 256,
     bk: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     B, S, H, h = q.shape
     Kh = k.shape[2]

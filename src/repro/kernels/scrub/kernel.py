@@ -8,7 +8,8 @@ MRAM scrubbing loop. Fused, in one HBM pass over (stored, mask):
 
     corrected = stored XOR mask  ->  stochastic re-write of the mask bits
     -> scrubbed word + RESIDUAL mask (re-writes that failed stay decayed and
-       are retried on the next pass) + per-block energy/flip/error sums.
+       are retried on the next pass) + per-block, per-bit-plane flip/error
+       counts.
 
 The re-write obeys the same EXTENT driver semantics as the write path: each
 corrected bit pays the level's per-direction flip energy and fails with the
@@ -16,112 +17,53 @@ level's direction WER (a failed correction leaves the decayed value — the
 cell kept its wrong state). Words with an all-zero mask are untouched at
 zero energy, the CMP redundant-write elimination applied to scrubbing.
 
-RNG/layout contract: identical to ``kernels/extent_write`` — counter hash of
-(seed, FLAT lane index, bit plane), so results are invariant to how ops.py
+RNG/layout/stats contract: identical to ``kernels/extent_write`` (the two
+kernels share its per-plane body and pallas_call) — counter hash of (seed,
+FLAT lane index, bit plane), so results are invariant to how ops.py
 partitions the lane vector into a grid, and ``ref.py`` reproduces the kernel
-bit-exactly in pure jnp.
+bit-exactly in pure jnp, energy included.
 """
 from __future__ import annotations
 
-import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-from repro.kernels.extent_write.kernel import DEFAULT_BLOCK, uniform_bits
+from repro.kernels.extent_write.kernel import (DEFAULT_BLOCK, flat_lane_index,
+                                               lane_call, rewrite_planes)
 
 
-def _kernel(
-    stored_ref, mask_ref, seed_ref, thr01_ref, thr10_ref, e01_ref, e10_ref,
-    scrubbed_ref, residual_ref, energy_ref, flips01_ref, flips10_ref,
-    errors_ref, *, nbits: int, block: Tuple[int, int], cols_total: int,
-):
-    r, c = pl.program_id(0), pl.program_id(1)
-    stored = stored_ref[...]
+def _kernel(stored_ref, mask_ref, seed_ref, lane0_ref, thr01_ref, thr10_ref,
+            scrubbed_ref, residual_ref, stats_ref, *, nbits: int,
+            block: Tuple[int, int], cols_total: int, stride: int):
     mask = mask_ref[...]
-    seed = seed_ref[0]
-
-    # global flat lane index of each lane in this block (layout-invariant)
-    row0 = r * block[0]
-    col0 = c * block[1]
-    rows = jax.lax.broadcasted_iota(jnp.uint32, block, 0) + jnp.uint32(row0)
-    cols = jax.lax.broadcasted_iota(jnp.uint32, block, 1) + jnp.uint32(col0)
-    elem = rows * jnp.uint32(cols_total) + cols
-
-    corrected = stored ^ mask
-    one = jnp.uint32(1)
-
-    fail_acc = jnp.zeros(block, jnp.uint32)
-    energy = jnp.zeros(block, jnp.float32)
-    n01 = jnp.zeros(block, jnp.uint32)
-    n10 = jnp.zeros(block, jnp.uint32)
-    nerr = jnp.zeros(block, jnp.uint32)
-
-    for b in range(nbits):  # static unroll: nbits is 16 or 32
-        bitmask = one << b
-        rewrite = (mask & bitmask) != 0                 # decayed -> re-write
-        to_ap = rewrite & ((corrected & bitmask) != 0)  # correcting to 1
-        u = uniform_bits(seed, elem, b)
-        thr = jnp.where(to_ap, thr01_ref[b], thr10_ref[b])
-        fail = rewrite & (u < thr)
-        fail_acc = fail_acc | jnp.where(fail, bitmask, jnp.uint32(0))
-        e_bit = jnp.where(to_ap, e01_ref[b], e10_ref[b])
-        energy = energy + jnp.where(rewrite, e_bit, 0.0)
-        n01 = n01 + to_ap.astype(jnp.uint32)
-        n10 = n10 + (rewrite & ~to_ap).astype(jnp.uint32)
-        nerr = nerr + fail.astype(jnp.uint32)
-
-    scrubbed_ref[...] = corrected ^ fail_acc  # failed bits stay decayed
-    residual_ref[...] = fail_acc              # retried on the next pass
-    energy_ref[0, 0] = jnp.sum(energy)
-    flips01_ref[0, 0] = jnp.sum(n01.astype(jnp.int32))
-    flips10_ref[0, 0] = jnp.sum(n10.astype(jnp.int32))
-    errors_ref[0, 0] = jnp.sum(nerr.astype(jnp.int32))
+    corrected = stored_ref[...] ^ mask
+    fail, stats_ref[...] = rewrite_planes(
+        seed_ref[0], flat_lane_index(block, cols_total, stride, lane0_ref[0]),
+        corrected, mask, thr01_ref, thr10_ref, nbits=nbits, block=block)
+    scrubbed_ref[...] = corrected ^ fail  # failed bits stay decayed
+    residual_ref[...] = fail              # retried on the next pass
 
 
 def scrub_kernel(
-    stored_u32: jax.Array,   # (R, C) uint32 lanes, R % block[0] == 0 etc.
-    mask_u32: jax.Array,     # (R, C) uint32 decayed-bit mask
+    stored_u32: jax.Array,   # (G, R, C) uint32 lanes
+    mask_u32: jax.Array,     # decayed-bit mask, same shape
     seed: jax.Array,         # (1,) uint32
     thr01: jax.Array,        # (nbits,) uint32 failure thresholds (wer * 2^32)
     thr10: jax.Array,
-    e01: jax.Array,          # (nbits,) f32 per-flip energies (pJ)
-    e10: jax.Array,
     *,
     nbits: int,
     block: Tuple[int, int] = DEFAULT_BLOCK,
-    interpret: bool = True,  # CPU container: validate via interpreter
+    lane0: Optional[jax.Array] = None,
+    stride: int = 0,
+    interpret: Optional[bool] = None,
 ):
-    """Returns (scrubbed (R,C) u32, residual_mask (R,C) u32, energy (gr,gc)
-    f32, flips01, flips10, errors (gr,gc) i32). Stats are per-block sums."""
-    R, C = stored_u32.shape
-    assert R % block[0] == 0 and C % block[1] == 0, (stored_u32.shape, block)
-    grid = (R // block[0], C // block[1])
-
-    vec_spec = pl.BlockSpec((nbits,), lambda r, c: (0,))
-    stat_spec = pl.BlockSpec((1, 1), lambda r, c: (r, c))
-    data_spec = pl.BlockSpec(block, lambda r, c: (r, c))
-
-    return pl.pallas_call(
-        functools.partial(_kernel, nbits=nbits, block=block, cols_total=C),
-        grid=grid,
-        in_specs=[
-            data_spec, data_spec,
-            pl.BlockSpec((1,), lambda r, c: (0,)),   # seed
-            vec_spec, vec_spec, vec_spec, vec_spec,
-        ],
-        out_specs=[
-            data_spec, data_spec, stat_spec, stat_spec, stat_spec, stat_spec,
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((R, C), jnp.uint32),
-            jax.ShapeDtypeStruct((R, C), jnp.uint32),
-            jax.ShapeDtypeStruct(grid, jnp.float32),
-            jax.ShapeDtypeStruct(grid, jnp.int32),
-            jax.ShapeDtypeStruct(grid, jnp.int32),
-            jax.ShapeDtypeStruct(grid, jnp.int32),
-        ],
-        interpret=interpret,
-    )(stored_u32, mask_u32, seed, thr01, thr10, e01, e10)
+    """Returns (scrubbed (G, R, C) u32, residual_mask (G, R, C) u32, stats
+    tiles — the write kernel's per-plane count layout); ``lane0`` and
+    ``stride`` place the groups as in ``extent_write_kernel``."""
+    if lane0 is None:
+        lane0 = jnp.zeros((1,), jnp.uint32)
+    return lane_call(_kernel, (stored_u32, mask_u32),
+                     (seed, lane0, thr01, thr10), 2, nbits=nbits,
+                     block=block, stride=stride, interpret=interpret)

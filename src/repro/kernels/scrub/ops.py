@@ -2,8 +2,9 @@
 
 Mirrors ``kernels/extent_write/ops.py``: dtype bitcasting into uint32 lanes
 (shared ``_to_lanes``/``_from_lanes`` plumbing), right-sized grids with
-row-block padding only, threshold/energy vector operands, per-block stat
-reduction, and auto-interpret on CPU hosts (``interpret=None``).
+row-block padding only, threshold/energy vector operands, the per-plane
+count-tile reduction (``tile_stats``), and ``interpret=None`` following the
+platform (``repro.kernels.platform``).
 
 The decay *mask* rides in element space (``uint_type(data.dtype)``, same
 shape as the data — maintained by ``repro.reliability.lifetime``) and is
@@ -22,7 +23,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.extent_write import kernel as WK
-from repro.kernels.extent_write.ops import _from_lanes, _to_lanes
+from repro.kernels.extent_write.ops import (Shard, _from_lanes, _layout,
+                                           _to_lanes, lane_kernel)
+from repro.kernels.platform import resolve_interpret
 from repro.kernels.scrub import kernel as K
 from repro.kernels.scrub import ref as R
 
@@ -38,6 +41,7 @@ def scrub_write(
     block: Tuple[int, int] = WK.DEFAULT_BLOCK,
     use_kernel: bool = True,
     interpret: Optional[bool] = None,
+    shard: Optional[Shard] = None,
 ) -> Tuple[jax.Array, jax.Array, Dict[str, jax.Array]]:
     """Corrective re-write of the decayed bits of ``stored`` (see kernel.py).
 
@@ -51,60 +55,44 @@ def scrub_write(
     errors, bits_written, bits_total}); ``residual_mask`` holds the
     corrections that FAILED (still-decayed bits, retried next pass);
     ``bits_total`` counts the scanned element bits, never the lane padding.
+    ``shard``: as in ``extent_write`` (kernel only).
     """
     assert stored.shape == mask.shape, (stored.shape, mask.shape)
     assert jnp.dtype(mask.dtype) == jnp.dtype(uint_type(stored.dtype)), (
         mask.dtype, stored.dtype)
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+    assert shard is None or use_kernel, "only the kernel scrubs a shard"
     thr01, thr10, e01, e10 = vectors
-    return _scrub_jit(key, stored, mask, thr01, thr10, e01, e10,
+    layout, lane0 = _layout(shard, stored)
+    return _scrub_jit(key, stored, mask, thr01, thr10, e01, e10, lane0,
                       block=block, use_kernel=use_kernel,
-                      interpret=interpret)
+                      interpret=resolve_interpret(interpret), layout=layout)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "use_kernel",
-                                             "interpret"))
+                                             "interpret", "layout"))
 def _scrub_jit(
-    key, stored, mask, thr01, thr10, e01, e10, *,
-    block: Tuple[int, int], use_kernel: bool, interpret: bool,
+    key, stored, mask, thr01, thr10, e01, e10, lane0, *,
+    block: Tuple[int, int], use_kernel: bool, interpret: bool, layout,
 ) -> Tuple[jax.Array, jax.Array, Dict[str, jax.Array]]:
     nbits = int(thr01.shape[0])
     seed = jax.random.bits(key, (1,), jnp.uint32)
 
     stored_u, _ = _to_lanes(stored)
     mask_u, _ = _to_lanes(mask)
-    n_lanes = stored_u.size
 
-    # same right-sized grid policy as extent_write: the counter RNG hashes
-    # the FLAT lane index, so any (rows, cols) partition is bit-identical —
-    # only rows are padded, to the row-block (never a full 256x512 pad).
+    # same layout policy as extent_write: the counter RNG hashes the FLAT
+    # lane index, so any (rows, cols) partition is bit-identical; padding
+    # lanes have mask == 0 -> no re-writes, no energy, no failures
     if use_kernel:
-        cols = block[1]
-        rows_used = max(1, -(-n_lanes // cols))
-        block_r = min(block[0], rows_used)
-        rows = -(-rows_used // block_r) * block_r
-    else:
-        cols = n_lanes if n_lanes else 1
-        rows = 1
-    pad = rows * cols - n_lanes
-    # padding lanes: mask == 0 -> no re-writes, no energy, no failures
-    stored2 = jnp.concatenate(
-        [stored_u, jnp.zeros((pad,), jnp.uint32)]).reshape(rows, cols)
-    mask2 = jnp.concatenate(
-        [mask_u, jnp.zeros((pad,), jnp.uint32)]).reshape(rows, cols)
-
-    if use_kernel:
-        scrubbed2, residual2, energy, f01, f10, err = K.scrub_kernel(
-            stored2, mask2, seed, thr01, thr10, e01, e10,
-            nbits=nbits, block=(min(block[0], rows), cols),
-            interpret=interpret)
-        stats = {"energy_pj": jnp.sum(energy),
-                 "flips01": jnp.sum(f01), "flips10": jnp.sum(f10),
-                 "errors": jnp.sum(err)}
+        (scrubbed_u, residual_u), stats = lane_kernel(
+            K.scrub_kernel, (stored_u, mask_u), seed,
+            (thr01, thr10, e01, e10), block=block, interpret=interpret,
+            layout=layout, lane0=lane0)
     else:
         scrubbed2, residual2, stats = R.scrub_ref(
-            stored2, mask2, seed, thr01, thr10, e01, e10, nbits=nbits)
+            stored_u.reshape(1, -1), mask_u.reshape(1, -1), seed, thr01,
+            thr10, e01, e10, nbits=nbits)
+        scrubbed_u, residual_u = scrubbed2.reshape(-1), residual2.reshape(-1)
 
     stats = dict(stats)
     stats["bits_written"] = stats["flips01"] + stats["flips10"]
@@ -114,7 +102,6 @@ def _scrub_jit(
         jnp.float32)
 
     ut = uint_type(stored.dtype)
-    scrubbed = _from_lanes(scrubbed2.reshape(-1)[:n_lanes], stored.shape,
-                           stored.dtype)
-    residual = _from_lanes(residual2.reshape(-1)[:n_lanes], mask.shape, ut)
+    scrubbed = _from_lanes(scrubbed_u, stored.shape, stored.dtype)
+    residual = _from_lanes(residual_u, mask.shape, ut)
     return scrubbed, residual, stats

@@ -12,7 +12,8 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.extent_write.ref import _uniform_bits_all
+from repro.kernels.extent_write.ref import (_uniform_bits_all,
+                                            plane_counts, plane_stats)
 
 
 def scrub_ref(
@@ -46,12 +47,5 @@ def scrub_ref(
                         dtype=jnp.uint32)
     scrubbed = corrected ^ fail_mask
 
-    e_bit = jnp.where(to_ap, e01, e10)
-    stats = {
-        "energy_pj": jnp.sum(jnp.where(rewrite, e_bit, 0.0),
-                             dtype=jnp.float32),
-        "flips01": jnp.sum(to_ap, dtype=jnp.int32),
-        "flips10": jnp.sum(rewrite & ~to_ap, dtype=jnp.int32),
-        "errors": jnp.sum(fail, dtype=jnp.int32),
-    }
-    return scrubbed, fail_mask, stats
+    return scrubbed, fail_mask, plane_stats(
+        *plane_counts(rewrite, to_ap, fail), e01, e10)

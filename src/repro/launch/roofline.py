@@ -335,7 +335,7 @@ class Roofline:
     def roofline_fraction(self) -> float:
         """MODEL_FLOPS-at-peak time / bound step time: the score we report.
         1.0 means every cycle is a useful model flop and nothing else binds."""
-        ideal = self.model_flops / (CHIPS * hw.PEAK_FLOPS_BF16)
+        ideal = self.model_flops / (CHIPS * hw.V5E.flops_bf16)
         return ideal / max(self.step_s, 1e-30)
 
 
@@ -351,9 +351,9 @@ def analyze(arch: str, shape_name: str,
         total_flops = fl["fwd"] + fl["bwd"] + strat.remat_factor * fl["fwd"]
     return Roofline(
         arch=arch, shape=shape_name,
-        compute_s=total_flops / (CHIPS * hw.PEAK_FLOPS_BF16),
-        memory_s=by["total"] / (CHIPS * hw.HBM_BW),
-        collective_s=co["total"] / hw.ICI_BW_PER_LINK,
+        compute_s=total_flops / (CHIPS * hw.V5E.flops_bf16),
+        memory_s=by["total"] / (CHIPS * hw.V5E.hbm_bw),
+        collective_s=co["total"] / hw.V5E.ici_bw_per_link,
         model_flops=fl["model_flops"],
         total_flops=total_flops,
         tokens=fl["tokens"],

@@ -65,12 +65,14 @@ import jax.numpy as jnp
 
 from repro.configs import get_config
 from repro.core.priority import Priority
+from repro.launch.compile_cache import use_compile_cache
 from repro.memory import available_backends
 from repro.serve import (ContinuousScheduler, ServeConfig, ServingEngine,
                          synthetic_requests)
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2.5-3b")
     ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,

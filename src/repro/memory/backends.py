@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import approx_store as _oracle
+from repro.kernels.platform import resolve_interpret
 from repro.memory.stats import WriteStats
 
 
@@ -62,7 +63,9 @@ class Backend(Protocol):
     def leaf_write(self, key: jax.Array, old: jax.Array, new: jax.Array,
                    lv: LeafVectors) -> Tuple[jax.Array, WriteStats]:
         """Write ``new`` over ``old`` (same shape/dtype); return the stored
-        tensor and device-resident unified WriteStats. Must be jit-safe."""
+        tensor and device-resident unified WriteStats. Must be jit-safe.
+        A backend whose ``maps_shards`` is true also takes ``shard=``
+        (its operands are one device's shard; ``WritePlan.leaf_call``)."""
         ...
 
     def leaf_scrub(self, key: jax.Array, stored: jax.Array,
@@ -112,7 +115,7 @@ class _CounterScrub:
     _scrub_use_kernel = False
     _scrub_interpret: Optional[bool] = None
 
-    def leaf_scrub(self, key, stored, mask, lv: LeafVectors):
+    def leaf_scrub(self, key, stored, mask, lv: LeafVectors, shard=None):
         if lv.thr01 is None:  # no lane packing for this element width
             return _planes_scrub(stored, mask, lv)
         from repro.kernels.scrub import ops as sops
@@ -120,7 +123,7 @@ class _CounterScrub:
             key, stored, mask,
             vectors=(lv.thr01, lv.thr10, lv.le01, lv.le10),
             use_kernel=self._scrub_use_kernel,
-            interpret=self._scrub_interpret)
+            interpret=self._scrub_interpret, shard=shard)
         flips = st["flips01"] + st["flips10"]
         return scrubbed, residual, WriteStats.for_bits(
             stored.size * jnp.dtype(stored.dtype).itemsize * 8,
@@ -149,26 +152,31 @@ class LaneBackend(_CounterScrub):
     """Lane-packed fused path (counter RNG over flat lane indices):
     ``use_kernel=False`` is the pure-jnp lane reference, ``use_kernel=True``
     the Pallas kernel (write AND scrub kernels). ``interpret=None`` resolves
-    at construction: the interpreter on CPU hosts, native elsewhere."""
+    at construction through ``repro.kernels.platform``: native on a TPU
+    (compiled and run on a v5e), the Pallas interpreter on other hosts.
+
+    The kernel cannot be partitioned by XLA: ``maps_shards`` tells
+    ``WritePlan.leaf_call`` to map it over a slot mesh itself, passing
+    each device's ``shard`` (``kernels.extent_write.ops.Shard``)."""
 
     def __init__(self, name: str, use_kernel: bool,
                  interpret: Optional[bool] = None):
         self.name = name
         self.use_kernel = use_kernel
+        self.maps_shards = use_kernel
         self._scrub_use_kernel = use_kernel
-        if interpret is None:
-            interpret = jax.default_backend() == "cpu"
-        self.interpret = interpret
-        self._scrub_interpret = interpret
+        self.interpret = resolve_interpret(interpret)
+        self._scrub_interpret = self.interpret
         self._oracle = OracleBackend()
 
-    def leaf_write(self, key, old, new, lv: LeafVectors):
+    def leaf_write(self, key, old, new, lv: LeafVectors, shard=None):
         if lv.thr01 is None:  # no lane packing for this element width
             return self._oracle.leaf_write(key, old, new, lv)
         from repro.kernels.extent_write import ops as xops
         stored, st = xops.extent_write(
             key, old, new, vectors=(lv.thr01, lv.thr10, lv.le01, lv.le10),
-            use_kernel=self.use_kernel, interpret=self.interpret)
+            use_kernel=self.use_kernel, interpret=self.interpret,
+            shard=shard)
         flips = st["flips01"] + st["flips10"]
         return stored, WriteStats.for_bits(
             old.size * jnp.dtype(old.dtype).itemsize * 8,

@@ -18,6 +18,10 @@ data needed), it captures
     the ring column at ``pos % C`` per slot — O(token) lane work per decode
     step instead of O(cache), with accounting identical to the full diff
     (everything outside the column is bit-unchanged => zero under CMP),
+  * the slot mesh: over a pool whose slot axis is split across devices,
+    a backend XLA cannot partition (the Pallas kernels) runs as a
+    ``shard_map`` in which each device writes its own slots
+    (``WritePlan.leaf_call``),
   * an optional post-write soft-error hook (retention upsets at
     ``soft_error_ber``; the hardened driver protects sign/exponent bits),
     surfaced through ``WriteStats.soft_strikes``.
@@ -35,6 +39,8 @@ from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh
+from jax.sharding import PartitionSpec as P
 
 from repro.core import write_driver
 from repro.core.approx_store import inject_soft_errors
@@ -131,6 +137,8 @@ class WritePlan:
     #: physical addressing layer (repro.memory.address): None = no remap,
     #: no stuck-at gating — the exact pre-address data path.
     address_spec: Optional[addr_mod.AddressSpec] = None
+    #: 1-D device mesh the slot (batch) axis is split over, or None
+    slot_mesh: Optional[Mesh] = None
     floor_vectors: Dict[Priority, Tuple[Optional[LeafVectors], ...]] = (
         dataclasses.field(default_factory=dict))
     _jit_write: Any = dataclasses.field(default=None, repr=False,
@@ -146,6 +154,7 @@ class WritePlan:
                  soft_error_ber: float = 0.0,
                  soft_error_hardened: bool = True,
                  address_spec: Optional[addr_mod.AddressSpec] = None,
+                 slot_mesh: Optional[Mesh] = None,
                  driver_cfg: Optional[write_driver.DriverConfig] = None,
                  approx_if: Callable[[Any, Priority], bool]
                  = _default_approx_if) -> "WritePlan":
@@ -181,7 +190,7 @@ class WritePlan:
                    leaf_seq_axis=seq_axis, batch_axis=batch_axis,
                    soft_error_ber=soft_error_ber,
                    soft_error_hardened=soft_error_hardened,
-                   address_spec=address_spec,
+                   address_spec=address_spec, slot_mesh=slot_mesh,
                    floor_vectors=floor_vectors)
 
     # ------------------------------------------------------ address layer
@@ -268,13 +277,54 @@ class WritePlan:
         return self.floor_vectors[Priority.coerce(floor)]
 
     # ----------------------------------------------------------- write paths
+    def leaf_call(self, method: str, key, a, b, lv: LeafVectors):
+        """``backend.<method>(key, a, b, lv)`` on one leaf, for
+        ``leaf_write`` and ``leaf_scrub``.
+
+        XLA cannot partition a Pallas kernel. Over a ``slot_mesh`` of
+        several devices, a backend that says so (``maps_shards``) runs as a
+        ``shard_map`` over the leaf's slot axis: each device handles its
+        own slots, told where they sit in the whole leaf (a kernel
+        ``Shard``), so the flat-lane RNG hashes the one-device indices, and
+        the per-plane counts are summed over the mesh before pricing. The
+        stored bits and the stats equal the one-device call's, with no
+        gather of the leaf. A leaf whose slots do not split evenly into
+        whole 32-bit lanes (an admission's one slot) runs whole on every
+        device."""
+        call = getattr(self.backend, method)
+        mesh = self.slot_mesh
+        if (mesh is None or mesh.size == 1 or lv.thr01 is None
+                or not getattr(self.backend, "maps_shards", False)):
+            return call(key, a, b, lv)
+        from repro.kernels.extent_write.ops import Shard
+        axis, n, bx = mesh.axis_names[0], mesh.size, self.batch_axis
+        B = a.shape[bx]
+        inner = int(np.prod(a.shape[bx + 1:]))
+        run = B // n * inner
+        spec, local = P(), call
+        if B % n == 0 and run * jnp.dtype(a.dtype).itemsize % 4 == 0:
+            spec = P(*([None] * bx), axis)
+
+            def local(key, a, b, lv):
+                return call(key, a, b, lv, shard=Shard(
+                    groups=int(np.prod(a.shape[:bx])), stride=B * inner,
+                    first=jax.lax.axis_index(axis) * run, axis=axis))
+        n_data = 1 if method == "leaf_write" else 2
+        *data, st = jax.shard_map(
+            local, mesh=mesh, in_specs=(P(), spec, spec, P()),
+            out_specs=(spec,) * n_data + (P(),), check_vma=False)(
+                key, a, b, lv)
+        whole = WriteStats.for_bits(a.size * bits_of(a.dtype))
+        return (*data, dataclasses.replace(st, bits_hi=whole.bits_hi,
+                                           bits_lo=whole.bits_lo))
+
     def _leaf_write(self, key, i: int, old, new,
                     lv: LeafVectors) -> Tuple[jax.Array, WriteStats]:
         """One leaf through the backend + the optional soft-error hook —
         the single place the per-leaf write protocol (RNG fold-in schedule
         included) lives."""
-        stored, st = self.backend.leaf_write(jax.random.fold_in(key, i),
-                                             old, new, lv)
+        stored, st = self.leaf_call("leaf_write", jax.random.fold_in(key, i),
+                                    old, new, lv)
         if self.soft_error_ber > 0.0:
             k_soft = jax.random.fold_in(key, _SOFT_KEY_OFFSET + i)
             stored, strikes = _soft_error_hook(

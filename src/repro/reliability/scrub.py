@@ -134,7 +134,6 @@ def scrub_tree(
             scrubbed_vec.append(0)
             continue
         k = jax.random.fold_in(key, _SCRUB_KEY_OFFSET + i)
-        be = plan.backend
         ax = plan.leaf_seq_axis[i]
         bx = plan.batch_axis
         windowed = cols is not None and ax is not None \
@@ -164,7 +163,8 @@ def scrub_tree(
             # under the scrub protocol) and stay decayed in the residual
             held = jnp.where(stuck, w_mask, jnp.zeros_like(w_mask))
             w_mask = jnp.where(stuck, jnp.zeros_like(w_mask), w_mask)
-        s_leaf, residual, st = be.leaf_scrub(k, w_leaf, w_mask, vectors[i])
+        s_leaf, residual, st = plan.leaf_call("leaf_scrub", k, w_leaf,
+                                              w_mask, vectors[i])
         if stuck is not None:
             residual = residual | held
         if windowed:

@@ -66,6 +66,7 @@ from repro.core.extent_table import QualityController
 from repro.core.priority import Priority, kv_cache_policy
 from repro.memory import WritePlan, WriteStats
 from repro.models import ModelApi, get_model
+from repro.sharding.mesh import die_device_mesh
 
 #: every family's cache leaves carry the request/slot dimension at axis 1
 #: (see ModelApi.cache_axes: ("layers", "batch", ...)).
@@ -81,8 +82,10 @@ class ServeConfig:
     temperature: float = 1.0
     seed: int = 0
     # EXTENT write-path backend: a repro.memory registry name. "lanes_ref"
-    # (pure-jnp lane path) is the fast jit-resident default on CPU hosts;
-    # "pallas" selects the kernel (auto-interpret on CPU, native on TPU);
+    # (pure-jnp lane path) is the default on every platform; "pallas"
+    # selects the write and scrub kernels — the Pallas interpreter off a
+    # TPU, native on one (chip_smoke.py runs it on a v5e, bit-identical
+    # to lanes_ref in tokens and the whole ledger);
     # "oracle" is the eager bit-unpacked reference; "exact" disables the
     # approximation model while keeping the data path.
     backend: str = "lanes_ref"
@@ -224,12 +227,16 @@ class ServingEngine:
             addr_spec = AddressSpec(
                 group_cols=serve_cfg.remap_group_cols,
                 endurance_budget=serve_cfg.endurance_budget)
+        # a pool split over dies (``shards``) splits the cache's slot axis
+        # over this mesh: kernels XLA cannot partition map over it per die
+        slot_mesh = (die_device_mesh(serve_cfg.shards)
+                     if serve_cfg.shards > 1 else None)
         self.plan = WritePlan.for_tree(
             cache_sds, policy=kv_cache_policy, backend=serve_cfg.backend,
             axes=self.api.cache_axes(), batch_axis=BATCH_AXIS,
             soft_error_ber=serve_cfg.soft_error_ber,
             soft_error_hardened=serve_cfg.soft_error_hardened,
-            address_spec=addr_spec)
+            address_spec=addr_spec, slot_mesh=slot_mesh)
         # the lifetime plan shadows the write plan when retention is on:
         # per-(leaf, floor, ambient) decay thresholds are operands, resolved
         # once — an ambient-temperature schedule swaps arrays between

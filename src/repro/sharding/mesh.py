@@ -18,11 +18,14 @@ burst regardless of ``n_dies``; per-die divergence (ambient temperature,
 scrub cadence, admission steering) enters exclusively through *operands*
 (per-slot threshold rows, per-die slot masks, admission score biases) that
 collapse to the legacy uniform shapes while the dies are indistinguishable.
-Inside the scan every slot's lane work, stat accumulation and decay
-sampling touches only that slot's rows — zero cross-die transfers, which
-is what lets decode throughput scale with dies (each die advances its
-shard without waiting on traffic from any other; the shard-locality lint
-rule and the benchmark's HLO collective grep enforce it stays that way).
+Inside the scan every slot's lane work and decay sampling touches only
+that slot's rows, which is what lets decode throughput scale with dies
+(each die advances its shard without waiting on another's data; the
+shard-locality lint rule keeps explicit collectives out of serve and
+reliability code). What crosses dies is the write statistics: the Pallas
+kernels, mapped per die by ``WritePlan.leaf_call``, sum their per-plane
+counts across dies (one small all-reduce per leaf and step on four v5e
+chips).
 
 ``make_production_mesh`` / ``make_host_mesh`` are the training-side pod
 meshes (formerly ``repro.launch.mesh``), kept as functions so importing
@@ -120,13 +123,8 @@ class DieMesh:
 
     # ------------------------------------------------------- jax sharding
     def device_mesh(self) -> Mesh:
-        """1-D ``jax.sharding.Mesh`` over the ``die`` axis. The axis size
-        is the largest divisor of ``n_dies`` the host's device count
-        admits (1 on a single-CPU host), so placement always succeeds and
-        dies fold evenly onto devices."""
-        devices = jax.devices()
-        k = _largest_divisor_leq(self.n_dies, len(devices))
-        return Mesh(np.asarray(devices[:k]), (DIE_AXIS,))
+        """See ``die_device_mesh``."""
+        return die_device_mesh(self.n_dies)
 
     def sharding_for(self, ndim: int, slot_axis: int) -> NamedSharding:
         """NamedSharding placing an array's ``slot_axis`` on the die
@@ -142,6 +140,16 @@ class DieMesh:
         return jax.tree.map(
             lambda a: jax.device_put(
                 a, self.sharding_for(a.ndim, slot_axis)), tree)
+
+
+def die_device_mesh(n_dies: int) -> Mesh:
+    """1-D ``jax.sharding.Mesh`` over the ``die`` axis for ``n_dies`` dies.
+    The axis size is the largest divisor of ``n_dies`` the host's device
+    count admits (1 on a single-CPU host), so placement always succeeds
+    and dies fold evenly onto devices."""
+    devices = jax.devices()
+    k = _largest_divisor_leq(n_dies, len(devices))
+    return Mesh(np.asarray(devices[:k]), (DIE_AXIS,))
 
 
 def uniform(values: Sequence) -> bool:

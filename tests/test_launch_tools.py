@@ -118,3 +118,49 @@ class TestStrategyRules:
             assert "embed" in rules
         with pytest.raises(KeyError):
             strategy_rules(mesh, "nope")
+
+
+class TestPeaks:
+    def test_v5e_peaks_by_device_kind(self):
+        from repro.launch import hw
+        p = hw.peaks("TPU v5 lite")
+        assert p.flops_bf16 == 197e12 and p.hbm_bw == 819e9
+
+    @pytest.mark.parametrize("kind", ["cpu", "TPU v4", "TPU v6 lite", ""])
+    def test_unknown_device_kind_raises(self, kind):
+        from repro.launch import hw
+        with pytest.raises(KeyError, match="no peak figures"):
+            hw.peaks(kind)
+
+    def test_running_device_is_looked_up(self):
+        from repro.launch import hw
+        dev = jax.devices()[0]
+        if dev.device_kind in hw.PEAKS:
+            assert hw.device_peaks() is hw.PEAKS[dev.device_kind]
+        else:
+            with pytest.raises(KeyError):
+                hw.device_peaks()
+
+
+class TestCompileCache:
+    def test_fixed_path_in_the_checkout_is_ignored_by_git(self, monkeypatch):
+        from pathlib import Path
+        from repro.launch import compile_cache as cc
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        was = jax.config.jax_compilation_cache_dir
+        try:
+            assert cc.use_compile_cache() == str(cc.CACHE_DIR)
+            assert jax.config.jax_compilation_cache_dir == str(cc.CACHE_DIR)
+        finally:
+            jax.config.update("jax_compilation_cache_dir", was)
+        repo = Path(__file__).resolve().parents[1]
+        assert cc.CACHE_DIR.parent == repo
+        ignored = (repo / ".gitignore").read_text().split()
+        assert f"{cc.CACHE_DIR.name}/" in ignored
+
+    def test_env_directory_is_left_to_jax(self, monkeypatch, tmp_path):
+        from repro.launch import compile_cache as cc
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        was = jax.config.jax_compilation_cache_dir
+        assert cc.use_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == was

@@ -138,12 +138,10 @@ class TestScrubKernel:
         np.testing.assert_array_equal(
             np.asarray(s_k).view(np.uint8), np.asarray(s_r).view(np.uint8))
         np.testing.assert_array_equal(np.asarray(r_k), np.asarray(r_r))
-        for k in ("flips01", "flips10", "errors"):
-            assert int(st_k[k]) == int(st_r[k]), k
-        # energy: same flips, different f32 reduction order (per-block
-        # partial sums in the kernel vs one global sum in the ref)
-        np.testing.assert_allclose(float(st_k["energy_pj"]),
-                                   float(st_r["energy_pj"]), rtol=1e-6)
+        # per-plane integer counts priced by one function: every stat,
+        # energy included, agrees exactly
+        for k in ("flips01", "flips10", "errors", "energy_pj"):
+            assert float(st_k[k]) == float(st_r[k]), k
 
     def test_perfect_scrub_restores_golden(self):
         """With zero failure thresholds every correction lands: the

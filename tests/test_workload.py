@@ -7,6 +7,7 @@ counters — on every write-path backend. Everything else (schema
 validation, generator determinism across processes, monotone pressure
 ordering, the prefix×wear adversarial migration) is behavioral.
 """
+import os
 import subprocess
 import sys
 from pathlib import Path
@@ -113,8 +114,9 @@ class TestGenerators:
         out = subprocess.run(
             [sys.executable, "-c", prog], capture_output=True, text=True,
             cwd=str(Path(__file__).parent.parent),
-            env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-                 "HOME": "/tmp"},
+            # the parent's environment (JAX_PLATFORMS included), so the
+            # child stays on the platform this run chose
+            env={**os.environ, "PYTHONPATH": "src"},
             check=True)
         assert out.stdout == here
 
