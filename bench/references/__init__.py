@@ -1,0 +1,3 @@
+"""Plain references: one module per architecture family, named by the
+``reference`` key of a configuration file, and ``extent``, the approximate
+write that every served cache goes through."""
