@@ -27,7 +27,7 @@ from benchmarks import (endurance_sweep, fig2_switching, fig6_thermal,
                         fig12_waveform, fig13_access, fig14_energy,
                         fig15_variation, kernel_bench, prefix_reuse,
                         retention_sweep, serving_energy, shard_scaling,
-                        table1, telemetry_overhead, workload_mixes)
+                        table1, workload_mixes)
 from repro.launch.compile_cache import use_compile_cache
 
 BENCHES = {
@@ -53,8 +53,6 @@ BENCHES = {
     "prefix_reuse": lambda fast: prefix_reuse.run(n=12 if fast else 16),
     "workload_mixes": lambda fast: workload_mixes.run(
         events=4 if fast else 6),
-    "telemetry_overhead": lambda fast: telemetry_overhead.run(
-        repeats=4 if fast else 6),
     "shard_scaling": lambda fast: shard_scaling.run(
         n=6 if fast else 8, repeats=2 if fast else 3),
 }
@@ -64,7 +62,7 @@ BENCHES = {
 #: accumulates (implies --fast; one invocation, one JSON)
 QUICK_BENCHES = ("table1", "fig6_thermal", "kernel_bench",
                  "retention_sweep", "endurance_sweep", "prefix_reuse",
-                 "workload_mixes", "telemetry_overhead", "shard_scaling")
+                 "workload_mixes", "shard_scaling")
 
 #: modules exposing ``bench_metrics(out)`` — the registration hook for the
 #: machine-readable report
@@ -75,7 +73,6 @@ _METRIC_FNS = {
     "endurance_sweep": endurance_sweep.bench_metrics,
     "prefix_reuse": prefix_reuse.bench_metrics,
     "workload_mixes": workload_mixes.bench_metrics,
-    "telemetry_overhead": telemetry_overhead.bench_metrics,
     "shard_scaling": shard_scaling.bench_metrics,
 }
 
@@ -125,10 +122,6 @@ def _headline(name: str, out) -> str:
                 f"{out['ramp'][-1]['pressure']:.2f} "
                 f"adversarial_worn none={adv['none']['worn_groups']:.0f} "
                 f"rotate={adv['rotate']['worn_groups']:.0f}")
-    if name == "telemetry_overhead":
-        return (f"overhead={out['overhead_frac']:+.3f} "
-                f"bit_exact={out['claims']['bit_exact_tokens']} "
-                f"drains/event={out['telemetry']['drains_per_event']:g}")
     if name == "shard_scaling":
         return (f"bit_identical="
                 f"{out['claims']['bit_identical_across_dies']} "

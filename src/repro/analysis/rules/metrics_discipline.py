@@ -1,7 +1,7 @@
 """R6 metrics-discipline — metrics flow through the telemetry registry
 and instrument drains stay out of traced regions.
 
-Two checks:
+Three checks:
 
   * **ad-hoc accumulators**: a module-level ``NAME = <number>`` that the
     same module mutates (an ``AugAssign`` target or a ``global``
@@ -18,6 +18,12 @@ Two checks:
     whole point of binding device accumulators is that they drain once
     per scheduler event, on the host control path.
 
+  * **bare trace marks**: ``jax.named_scope`` or ``TraceAnnotation``
+    outside ``repro.telemetry``. Device scopes and host phases are
+    declared once in ``repro.telemetry.scopes`` (layer, doc, the metric
+    that reads them) and entered through its ``scope()``/``phase()``, so a
+    rename cannot silently drop a benchmark metric.
+
 Waivers use the standard protocol: a
 ``# repro: allow(metrics-discipline): …`` comment on the finding line or
 the line above, naming the budget/justification.
@@ -29,11 +35,15 @@ from typing import Iterator
 
 from repro.analysis.engine import (Finding, RepoContext, Rule, SourceFile,
                                    register_rule)
-from repro.analysis.visitors import walk_calls
+from repro.analysis.visitors import dotted, walk_calls
 
 #: the instrument sync entry points (Instruments.drain/resolve,
 #: Telemetry.event/finalize)
 DRAIN_METHODS = {"drain", "event", "resolve", "finalize"}
+
+#: trace marks that only ``repro.telemetry`` may create directly
+MARK_NAMES = {"named_scope", "TraceAnnotation"}
+MARKS_HOME = "src/repro/telemetry/"
 
 
 def _module_numeric_assigns(tree: ast.Module) -> dict:
@@ -61,11 +71,25 @@ def _mutated_names(tree: ast.Module) -> set:
     return mutated
 
 
+def _mark_name(node: ast.AST):
+    """``named_scope``/``TraceAnnotation`` when ``node`` names one: an
+    attribute (``jax.named_scope``, ``jax.profiler.TraceAnnotation``) or
+    an import of it."""
+    if isinstance(node, ast.Attribute) and node.attr in MARK_NAMES:
+        return dotted(node) or node.attr
+    if isinstance(node, ast.ImportFrom):
+        for a in node.names:
+            if a.name in MARK_NAMES:
+                return a.name
+    return None
+
+
 class MetricsDiscipline(Rule):
     name = "metrics-discipline"
     contract = ("every counter/gauge/histogram is declared through the "
                 "telemetry registry; instrument drains stay out of "
-                "traced regions")
+                "traced regions; trace scopes and phases are declared in "
+                "repro.telemetry.scopes")
 
     def check(self, sf: SourceFile, ctx: RepoContext) -> Iterator[Finding]:
         assigns = _module_numeric_assigns(sf.tree)
@@ -78,6 +102,15 @@ class MetricsDiscipline(Rule):
                     "exporters) — declare a counter/gauge through "
                     "repro.telemetry.registry.REGISTRY and count it on "
                     "an Instruments surface")
+        if not sf.rel.startswith(MARKS_HOME):
+            for node in ast.walk(sf.tree):
+                name = _mark_name(node)
+                if name is not None:
+                    yield self.finding(
+                        sf, node,
+                        f"bare {name} — declare the scope or phase in "
+                        "repro.telemetry.scopes.MARKS and enter it "
+                        "through scope()/phase()")
         tm = sf.trace_map()
         for call in walk_calls(sf.tree):
             if tm.under_compile_time_eval(call):

@@ -50,6 +50,7 @@ from repro.memory import address as addr_mod
 from repro.memory.backends import Backend, LeafVectors, get_backend
 from repro.memory.rng_streams import SOFT_ERROR_OFFSET as _SOFT_KEY_OFFSET
 from repro.memory.stats import WriteStats
+from repro.telemetry.scopes import scope
 
 
 def leaf_vectors(dtype, level, cfg: Optional[write_driver.DriverConfig] = None,
@@ -323,15 +324,16 @@ class WritePlan:
         """One leaf through the backend + the optional soft-error hook —
         the single place the per-leaf write protocol (RNG fold-in schedule
         included) lives."""
-        stored, st = self.leaf_call("leaf_write", jax.random.fold_in(key, i),
-                                    old, new, lv)
-        if self.soft_error_ber > 0.0:
-            k_soft = jax.random.fold_in(key, _SOFT_KEY_OFFSET + i)
-            stored, strikes = _soft_error_hook(
-                k_soft, stored, self.soft_error_ber,
-                self.soft_error_hardened)
-            st = dataclasses.replace(st,
-                                     soft_strikes=st.soft_strikes + strikes)
+        with scope("extent_lanes"):
+            stored, st = self.leaf_call(
+                "leaf_write", jax.random.fold_in(key, i), old, new, lv)
+            if self.soft_error_ber > 0.0:
+                k_soft = jax.random.fold_in(key, _SOFT_KEY_OFFSET + i)
+                stored, strikes = _soft_error_hook(
+                    k_soft, stored, self.soft_error_ber,
+                    self.soft_error_hardened)
+                st = dataclasses.replace(
+                    st, soft_strikes=st.soft_strikes + strikes)
         return stored, st
 
     def _alias_keep(self, i: int, leaf, alias_cols) -> Optional[jax.Array]:
@@ -460,8 +462,9 @@ class WritePlan:
             idx = (pos % C).reshape(ishape)
             gshape = o.shape[:ax] + (1,) + o.shape[ax + 1:]
             idx_g = jnp.broadcast_to(idx, gshape)
-            o_col = jnp.take_along_axis(o, idx_g, axis=ax)
-            n_col = jnp.take_along_axis(n, idx_g, axis=ax)
+            with scope("extent_gather"):
+                o_col = jnp.take_along_axis(o, idx_g, axis=ax)
+                n_col = jnp.take_along_axis(n, idx_g, axis=ax)
             if alias_cols is not None:
                 keep = (pos < alias_cols).reshape(ishape)
                 n_col = jnp.where(keep, o_col, n_col)
@@ -473,8 +476,10 @@ class WritePlan:
             s_col, st = self._leaf_write(key, i, o_col, n_col, vectors[i])
             if lost is not None:
                 st = dataclasses.replace(st, errors=st.errors + lost)
-            hit = jax.lax.broadcasted_iota(jnp.int32, o.shape, ax) == idx
-            stored.append(jnp.where(hit, s_col, n))
+            with scope("extent_store"):
+                hit = (jax.lax.broadcasted_iota(jnp.int32, o.shape, ax)
+                       == idx)
+                stored.append(jnp.where(hit, s_col, n))
             acc = acc + st
         return treedef.unflatten(stored), acc
 

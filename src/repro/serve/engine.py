@@ -67,6 +67,7 @@ from repro.core.priority import Priority, kv_cache_policy
 from repro.memory import WritePlan, WriteStats
 from repro.models import ModelApi, get_model
 from repro.sharding.mesh import die_device_mesh
+from repro.telemetry.scopes import scope
 
 #: every family's cache leaves carry the request/slot dimension at axis 1
 #: (see ModelApi.cache_axes: ("layers", "batch", ...)).
@@ -316,14 +317,18 @@ class ServingEngine:
         """
         def prefill(params, batch, old_rows, key, vectors):
             key, k_write, k_sample = jax.random.split(key, 3)
-            logits, cache = self.api.prefill(params, batch,
-                                             self.scfg.max_seq)
+            with scope("admit_model"):
+                logits, cache = self.api.prefill(params, batch,
+                                                 self.scfg.max_seq)
             acc = WriteStats.zero()
             if self.scfg.extent_enabled:
-                old = (old_rows if diff_old_rows
-                       else jax.tree.map(jnp.zeros_like, cache))
-                cache, acc = self.plan.write(k_write, old, cache, vectors)
-            tok = self._sample(k_sample, logits)
+                with scope("extent_write"):
+                    old = (old_rows if diff_old_rows
+                           else jax.tree.map(jnp.zeros_like, cache))
+                    cache, acc = self.plan.write(k_write, old, cache,
+                                                 vectors)
+            with scope("sample"):
+                tok = self._sample(k_sample, logits)
             return tok, cache, key, acc
 
         if diff_old_rows:
@@ -347,13 +352,17 @@ class ServingEngine:
         """
         def prefill(params, batch, old_rows, key, vectors, alias_cols):
             key, k_write, k_sample = jax.random.split(key, 3)
-            logits, cache = self.api.prefill(params, batch,
-                                             self.scfg.max_seq)
+            with scope("admit_model"):
+                logits, cache = self.api.prefill(params, batch,
+                                                 self.scfg.max_seq)
             acc = WriteStats.zero()
             if self.scfg.extent_enabled:
-                cache, acc = self.plan.write(k_write, old_rows, cache,
-                                             vectors, alias_cols=alias_cols)
-            tok = self._sample(k_sample, logits)
+                with scope("extent_write"):
+                    cache, acc = self.plan.write(
+                        k_write, old_rows, cache, vectors,
+                        alias_cols=alias_cols)
+            with scope("sample"):
+                tok = self._sample(k_sample, logits)
             return tok, cache, key, acc
 
         return prefill
@@ -395,9 +404,11 @@ class ServingEngine:
                       vectors, life, rvec, shifts=None):
             act_i = active.astype(jnp.int32)
             key, k_write, k_sample = jax.random.split(key, 3)
-            logits, new_cache = self.api.decode_step(
-                params, tok, cache, pos, self.scfg.max_seq)
-            new_cache = mask_rows(new_cache, cache, active)
+            with scope("decode_model"):
+                logits, new_cache = self.api.decode_step(
+                    params, tok, cache, pos, self.scfg.max_seq)
+            with scope("mask_rows"):
+                new_cache = mask_rows(new_cache, cache, active)
             if self.scfg.extent_enabled:
                 if wear:
                     # physical addressing: the written column's address
@@ -406,32 +417,39 @@ class ServingEngine:
                     # per-group endurance wear. Shifts/worn are operands
                     # (worn derives from the carried life state), so a
                     # rotation or a mid-burst failure never retraces.
-                    worn = self.life_plan.worn_groups(life)
-                    new_cache, st = self.plan.write_columns(
-                        k_write, cache, new_cache, pos, vectors,
-                        addr=(shifts, worn))
-                    life = self.life_plan.record_column_write(
-                        life, new_cache, pos, active, shifts)
+                    with scope("extent_write"):
+                        worn = self.life_plan.worn_groups(life)
+                        new_cache, st = self.plan.write_columns(
+                            k_write, cache, new_cache, pos, vectors,
+                            addr=(shifts, worn))
+                    with scope("extent_stats"):
+                        life = self.life_plan.record_column_write(
+                            life, new_cache, pos, active, shifts)
                 else:
-                    new_cache, st = self.plan.write_columns(
-                        k_write, cache, new_cache, pos, vectors)
-                acc = acc + st
-                slot_acc = add_slot_stats(slot_acc, st, active)
+                    with scope("extent_write"):
+                        new_cache, st = self.plan.write_columns(
+                            k_write, cache, new_cache, pos, vectors)
+                with scope("extent_stats"):
+                    acc = acc + st
+                    slot_acc = add_slot_stats(slot_acc, st, active)
             if retention:
                 # the step re-wrote the active slots' ring columns: their
                 # decay record is void (stale bits would make a later
                 # scrub corrupt live data) ...
-                life = self.life_plan.clear_written(life, pos, active)
+                with scope("retention_clear"):
+                    life = self.life_plan.clear_written(life, pos, active)
                 # ... then dwell one step at ambient T: every stored bit
                 # of the approximate leaves may decay. The retention
                 # sub-streams fold off k_write, so the write/sample RNG
                 # schedule is IDENTICAL with retention on or off — a
                 # 300 K run (all decay thresholds clamp to zero) is
                 # bit-identical to a retention-disabled run.
-                new_cache, life = self.life_plan.advance(
-                    k_write, new_cache, life, rvec)
-            tok2 = self._sample(k_sample, logits)
-            tok2 = jnp.where(active, tok2, tok)
+                with scope("retention_decay"):
+                    new_cache, life = self.life_plan.advance(
+                        k_write, new_cache, life, rvec)
+            with scope("sample"):
+                tok2 = self._sample(k_sample, logits)
+                tok2 = jnp.where(active, tok2, tok)
             return tok2, new_cache, pos + act_i, key, acc, slot_acc, life
 
         if wear:
@@ -485,17 +503,19 @@ class ServingEngine:
             def scrub(key, cache, life, vectors, cursor, shifts,
                       slot_mask=None, *, enabled, cols):
                 # the cursor walks PHYSICAL rows; worn rows stay decayed
-                worn = self.life_plan.worn_groups(life)
-                return scrub_tree(key, cache, life, self.life_plan,
-                                  vectors, enabled=enabled, cols=cols,
-                                  cursor=cursor, addr=(shifts, worn),
-                                  slot_mask=slot_mask)
+                with scope("scrub"):
+                    worn = self.life_plan.worn_groups(life)
+                    return scrub_tree(key, cache, life, self.life_plan,
+                                      vectors, enabled=enabled, cols=cols,
+                                      cursor=cursor, addr=(shifts, worn),
+                                      slot_mask=slot_mask)
         else:
             def scrub(key, cache, life, vectors, cursor, slot_mask=None,
                       *, enabled, cols):
-                return scrub_tree(key, cache, life, self.life_plan,
-                                  vectors, enabled=enabled, cols=cols,
-                                  cursor=cursor, slot_mask=slot_mask)
+                with scope("scrub"):
+                    return scrub_tree(key, cache, life, self.life_plan,
+                                      vectors, enabled=enabled, cols=cols,
+                                      cursor=cursor, slot_mask=slot_mask)
 
         return scrub
 

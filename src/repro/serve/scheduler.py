@@ -44,6 +44,7 @@ from repro.serve.prefix import PrefixCache, PrefixConfig, PrefixMatch
 from repro.serve.slots import SlotPool
 from repro.sharding import DieMesh, uniform
 from repro.telemetry import LANE_BACKGROUND, Lazy, Telemetry
+from repro.telemetry.scopes import phase
 
 
 @dataclasses.dataclass
@@ -361,6 +362,23 @@ class ContinuousScheduler:
                                    idle=self.pool.free_slots() > 0)
         if enabled is None:
             return
+        with phase("sched_scrub", clock=clock) as ph:
+            span_args = self._scrub_pass(clock, key, enabled)
+        if self.tele is not None:
+            # scrub interference is visible on the background lane over
+            # the same clock; the co-resident requests it contends with
+            # are named in the span args. The pass energy is a lazy
+            # device ref resolved at finalize — no sync here.
+            self.tele.instruments.inc("serve_scrub_passes_total")
+            self.tele.tracer.complete(
+                "scrub_pass", clock, clock, lane=LANE_BACKGROUND,
+                track="scrub", cat="reliability", t0_ns=ph.t0_ns,
+                t1_ns=ph.t1_ns, **span_args)
+
+    def _scrub_pass(self, clock: int, key, enabled) -> Optional[Dict]:
+        """One due scrub pass (plus the hotter dies' extra passes);
+        returns the first pass's span args when telemetry is on."""
+        eng, policy = self.eng, self.scrub_policy
         # the scrub controller re-resolves the quality of the blocks it is
         # about to re-write through the SAME LRU table as admissions — its
         # traffic lands in the "scrub" scope so it never inflates the serve
@@ -390,20 +408,13 @@ class ContinuousScheduler:
                 k, self.pool.cache, self.life, vectors, cursor,
                 enabled=enabled, cols=cols)
         self._acc_scrub = self._acc_scrub + st
+        span_args = None
         if self.tele is not None:
-            # scrub interference is visible on the background lane over
-            # the same clock; the co-resident requests it contends with
-            # are named in the span args. The pass energy is a lazy
-            # device ref resolved at finalize — no sync here.
             from repro.reliability.scrub import scrub_span_args
-            self.tele.instruments.inc("serve_scrub_passes_total")
-            self.tele.tracer.complete(
-                "scrub_pass", clock, clock, lane=LANE_BACKGROUND,
-                track="scrub", cat="reliability",
-                **scrub_span_args(
-                    st, policy, cols=cols or 0, floor=Priority(floor),
-                    resident=[self.pool.slot_req[i].rid
-                              for i in self.pool.occupied()]))
+            span_args = scrub_span_args(
+                st, policy, cols=cols or 0, floor=Priority(floor),
+                resident=[self.pool.slot_req[i].rid
+                          for i in self.pool.occupied()])
         policy.record(clock)
         self._scrub_passes += 1
         for d in range(self.mesh.n_dies):
@@ -435,6 +446,7 @@ class ContinuousScheduler:
         if cols:
             self._scrub_cursor = (self._scrub_cursor + cols) % \
                 eng.scfg.max_seq
+        return span_args
 
     # ------------------------------------------------------- wear leveling
     def _remap_stats(self) -> WriteStats:
@@ -460,6 +472,12 @@ class ContinuousScheduler:
         if clock - self._last_wear_check < max(1, interval):
             return
         self._last_wear_check = clock
+        with phase("sched_wear", clock=clock):
+            self._wear_check(clock)
+
+    def _wear_check(self, clock: int) -> None:
+        """The checkpoint's read, then a rotation where the policy asks."""
+        eng, pol = self.eng, self.wear_policy
         # repro: allow(no-host-sync-in-scan): the ONE wear-checkpoint sync,
         wear, scores = jax.device_get(  # amortized over check_interval
             (self.life.row_wear(),
@@ -614,143 +632,156 @@ class ContinuousScheduler:
             groups.setdefault(_prompt_signature(r.prompt), []).append(r)
         n_done = 0
         for group in groups.values():
-            for r in group:
-                self._level[r.rid] = self._resolve_quality(r)
-            # prefix resolution: match each member's prompt chain against
-            # the CAM, exclude match owners from allocation (linking to a
-            # slot about to be overwritten would be self-defeating), and
-            # CoW/drop until the group fits the allocatable slots.
-            matches: List[Optional[PrefixMatch]] = [None] * len(group)
-            sigs: List[Any] = []
-            exclude: set = set()
-            if self.prefix is not None:
-                matches, sigs = self._resolve_prefix(group)
-                exclude = {m.slot for m in matches if m is not None}
-                self._make_room(len(group), matches, exclude, clock)
-            # wear-aware admission: HIGH-quality requests steer away from
-            # slots backed by high-wear / high-residual-decay rows (scores
-            # from the last wear checkpoint — no extra sync here). LOW/MID
-            # admissions keep the lowest-id order the bit-parity contract
-            # rests on.
-            scores = None
-            high = max(self._level[r.rid] for r in group) >= Priority.HIGH
-            if (self.eng.wear and self._slot_scores_host is not None
-                    and high):
-                scores = self._slot_scores_host
-            if high:
-                # cross-shard steering: once the dies are observably
-                # unequal, HIGH requests prefer the healthy/cool dies
-                # (per-die bias on top of the per-slot wear scores; ties
-                # keep the lowest-id order)
-                bias = self._die_bias(clock)
-                if bias is None:
-                    pass
-                elif scores is None:
-                    scores = bias
-                else:
-                    # repro: allow(no-host-sync-in-scan): scores crossed at the wear checkpoint
-                    scores = np.asarray(scores) + bias
-            ids = self.pool.alloc(len(group), scores=scores,
-                                  exclude=sorted(exclude))
-            vectors = self.eng.vectors_for_floor(
-                max(self._floor(),
-                    max(self._level[r.rid] for r in group)))
-            batch = _stack_prompts(group)
-            old_rows = self.pool.extract_rows(ids)
-            pos0 = [self.eng.prompt_len(r.prompt) for r in group]
-            any_link = any(m is not None for m in matches)
-            if any_link:
-                # linked admission: splice the owners' resident prefix
-                # columns into the evicted rows, then write with those
-                # columns aliased — CMP sees zero changed bits there, so
-                # the linked prefix costs zero energy and zero WER
-                # exposure. RNG split schedule identical to _admit_fused.
-                owner_ids = [m.slot if m is not None else ids[j]
-                             for j, m in enumerate(matches)]
-                alias_list = [m.cols if m is not None else 0
-                              for m in matches]
-                alias = jnp.asarray(alias_list, jnp.int32)
-                owner_rows = self.pool.extract_rows(owner_ids)
-                old_rows = self.eng._splice_rows(old_rows, owner_rows,
-                                                 alias)
-                tok, rows, key, acc = self.eng._admit_linked_fused(
-                    self.eng.params, batch, old_rows, key, vectors, alias)
-            else:
-                alias_list = [0] * len(group)
-                tok, rows, key, acc = self.eng._admit_fused(
-                    self.eng.params, batch, old_rows, key, vectors)
-            self._acc_prefill = self.pool.admit(
-                ids, group, rows, tok, pos0, acc, self._acc_prefill)
-            if self.life is not None:
-                # the admitted rows were just prefill-written: their decay
-                # record restarts from zero (jitted, stays on device) —
-                # linked columns instead inherit the owner's decay record
-                # (their bits ARE the owner's stored bits, decay included)
-                idx = jnp.asarray(ids, jnp.int32)
-                if any_link:
-                    self.life = self.eng._life_reset_linked(
-                        self.life, idx,
-                        jnp.asarray(owner_ids, jnp.int32),
-                        jnp.asarray(alias_list, jnp.int32))
-                else:
-                    self.life = self.eng._life_reset(self.life, idx)
-                if self.prefix is not None and self.eng.wear:
-                    # endurance booking of the prompt-window row drives,
-                    # minus the linked columns — shared physical columns
-                    # wear ONCE, at their owner's admission
-                    self.life = self.eng._life_admit(
-                        self.life, self.pool.cache, idx,
-                        jnp.asarray(alias_list, jnp.int32),
-                        jnp.asarray(pos0, jnp.int32), self.addr.shifts)
-            if self.prefix is not None:
-                for j, r in enumerate(group):
-                    m = matches[j]
-                    if m is not None:
-                        self.pool.link(ids[j], m.slot, m.cols)
-                        pj, bits = self._alias_price(m.cols)
-                        self._saved_pj += pj
-                        self._saved_bits += bits
-                        self._linked_admissions += 1
-                        self._linked_cols += m.cols
-                    self.prefix.insert(
-                        sigs[j], ids[j], self.pool.generation[ids[j]],
-                        col_offset=pos0[j]
-                        - r.prompt["tokens"].shape[1])
-            for j, r in enumerate(group):
-                self._tokens[r.rid] = [(tok, j, 1)]
-                self._remaining[r.rid] = r.new_tokens - 1
-                self._admitted[r.rid] = clock
+            with phase("sched_admit", requests=len(group)) as ph:
+                key, ids, matches, acc = self._admit_group(group, clock,
+                                                           key)
             if self.tele is not None:
-                # per-request span tree: root (arrival->completion) with
-                # queue + prefill children. Prefill energy attribution is
-                # the group accumulator's even split, kept as a lazy
-                # device ref until finalize.
-                self.tele.instruments.inc("serve_admissions_total",
-                                          len(group))
-                share = Lazy(lambda e, k=len(group): e / k,
-                             acc.energy_pj)
-                for j, r in enumerate(group):
-                    track = self._req_track(r.rid)
-                    root = self.tele.tracer.begin(
-                        f"req {r.rid}", r.arrival, track=track,
-                        cat="request", rid=r.rid, app_id=str(r.app_id),
-                        quality=self._level[r.rid].name)
-                    self._req_span[r.rid] = root
-                    self.tele.tracer.complete(
-                        "queue", r.arrival, clock, track=track,
-                        cat="request", parent=root)
-                    m = matches[j]
-                    pargs = dict(group=len(group), slot=ids[j],
-                                 energy_pj=share)
-                    if m is not None:
-                        self.tele.instruments.inc(
-                            "serve_prefix_linked_total")
-                        pargs.update(m.span_args())
-                    self.tele.tracer.complete(
-                        "prefill", clock, clock, track=track,
-                        cat="prefill", parent=root, **pargs)
+                with phase("sched_telemetry"):
+                    self._book_admission(group, clock, ids, matches, acc,
+                                         ph)
             n_done += self._complete(clock)
         return key, n_done
+
+    def _admit_group(self, group: List[Request], clock: int, key):
+        """Prefill one prompt-shape group into free slots and install it
+        in the pool. Returns (key, slot ids, prefix matches, the write's
+        WriteStats)."""
+        for r in group:
+            self._level[r.rid] = self._resolve_quality(r)
+        # prefix resolution: match each member's prompt chain against
+        # the CAM, exclude match owners from allocation (linking to a
+        # slot about to be overwritten would be self-defeating), and
+        # CoW/drop until the group fits the allocatable slots.
+        matches: List[Optional[PrefixMatch]] = [None] * len(group)
+        sigs: List[Any] = []
+        exclude: set = set()
+        if self.prefix is not None:
+            matches, sigs = self._resolve_prefix(group)
+            exclude = {m.slot for m in matches if m is not None}
+            self._make_room(len(group), matches, exclude, clock)
+        # wear-aware admission: HIGH-quality requests steer away from
+        # slots backed by high-wear / high-residual-decay rows (scores
+        # from the last wear checkpoint — no extra sync here). LOW/MID
+        # admissions keep the lowest-id order the bit-parity contract
+        # rests on.
+        scores = None
+        high = max(self._level[r.rid] for r in group) >= Priority.HIGH
+        if (self.eng.wear and self._slot_scores_host is not None
+                and high):
+            scores = self._slot_scores_host
+        if high:
+            # cross-shard steering: once the dies are observably
+            # unequal, HIGH requests prefer the healthy/cool dies
+            # (per-die bias on top of the per-slot wear scores; ties
+            # keep the lowest-id order)
+            bias = self._die_bias(clock)
+            if bias is None:
+                pass
+            elif scores is None:
+                scores = bias
+            else:
+                # repro: allow(no-host-sync-in-scan): scores crossed at the wear checkpoint
+                scores = np.asarray(scores) + bias
+        ids = self.pool.alloc(len(group), scores=scores,
+                              exclude=sorted(exclude))
+        vectors = self.eng.vectors_for_floor(
+            max(self._floor(),
+                max(self._level[r.rid] for r in group)))
+        batch = _stack_prompts(group)
+        old_rows = self.pool.extract_rows(ids)
+        pos0 = [self.eng.prompt_len(r.prompt) for r in group]
+        any_link = any(m is not None for m in matches)
+        if any_link:
+            # linked admission: splice the owners' resident prefix
+            # columns into the evicted rows, then write with those
+            # columns aliased — CMP sees zero changed bits there, so
+            # the linked prefix costs zero energy and zero WER
+            # exposure. RNG split schedule identical to _admit_fused.
+            owner_ids = [m.slot if m is not None else ids[j]
+                         for j, m in enumerate(matches)]
+            alias_list = [m.cols if m is not None else 0
+                          for m in matches]
+            alias = jnp.asarray(alias_list, jnp.int32)
+            owner_rows = self.pool.extract_rows(owner_ids)
+            old_rows = self.eng._splice_rows(old_rows, owner_rows,
+                                             alias)
+            tok, rows, key, acc = self.eng._admit_linked_fused(
+                self.eng.params, batch, old_rows, key, vectors, alias)
+        else:
+            alias_list = [0] * len(group)
+            tok, rows, key, acc = self.eng._admit_fused(
+                self.eng.params, batch, old_rows, key, vectors)
+        self._acc_prefill = self.pool.admit(
+            ids, group, rows, tok, pos0, acc, self._acc_prefill)
+        if self.life is not None:
+            # the admitted rows were just prefill-written: their decay
+            # record restarts from zero (jitted, stays on device) —
+            # linked columns instead inherit the owner's decay record
+            # (their bits ARE the owner's stored bits, decay included)
+            idx = jnp.asarray(ids, jnp.int32)
+            if any_link:
+                self.life = self.eng._life_reset_linked(
+                    self.life, idx,
+                    jnp.asarray(owner_ids, jnp.int32),
+                    jnp.asarray(alias_list, jnp.int32))
+            else:
+                self.life = self.eng._life_reset(self.life, idx)
+            if self.prefix is not None and self.eng.wear:
+                # endurance booking of the prompt-window row drives,
+                # minus the linked columns — shared physical columns
+                # wear ONCE, at their owner's admission
+                self.life = self.eng._life_admit(
+                    self.life, self.pool.cache, idx,
+                    jnp.asarray(alias_list, jnp.int32),
+                    jnp.asarray(pos0, jnp.int32), self.addr.shifts)
+        if self.prefix is not None:
+            for j, r in enumerate(group):
+                m = matches[j]
+                if m is not None:
+                    self.pool.link(ids[j], m.slot, m.cols)
+                    pj, bits = self._alias_price(m.cols)
+                    self._saved_pj += pj
+                    self._saved_bits += bits
+                    self._linked_admissions += 1
+                    self._linked_cols += m.cols
+                self.prefix.insert(
+                    sigs[j], ids[j], self.pool.generation[ids[j]],
+                    col_offset=pos0[j]
+                    - r.prompt["tokens"].shape[1])
+        for j, r in enumerate(group):
+            self._tokens[r.rid] = [(tok, j, 1)]
+            self._remaining[r.rid] = r.new_tokens - 1
+            self._admitted[r.rid] = clock
+        return key, ids, matches, acc
+
+    def _book_admission(self, group: List[Request], clock: int,
+                        ids: List[int], matches, acc, ph) -> None:
+        """Per-request span tree: root (arrival->completion) with queue +
+        prefill children. Prefill energy attribution is the group
+        accumulator's even split, kept as a lazy device ref until
+        finalize; the prefill span carries the admission phase's
+        ``perf_counter_ns`` bounds."""
+        self.tele.instruments.inc("serve_admissions_total", len(group))
+        share = Lazy(lambda e, k=len(group): e / k, acc.energy_pj)
+        for j, r in enumerate(group):
+            track = self._req_track(r.rid)
+            root = self.tele.tracer.begin(
+                f"req {r.rid}", r.arrival, track=track,
+                cat="request", rid=r.rid, app_id=str(r.app_id),
+                quality=self._level[r.rid].name)
+            self._req_span[r.rid] = root
+            self.tele.tracer.complete(
+                "queue", r.arrival, clock, track=track,
+                cat="request", parent=root)
+            m = matches[j]
+            pargs = dict(group=len(group), slot=ids[j], energy_pj=share,
+                         t0_ns=ph.t0_ns, t1_ns=ph.t1_ns)
+            if m is not None:
+                self.tele.instruments.inc("serve_prefix_linked_total")
+                pargs.update(m.span_args())
+            self.tele.tracer.complete(
+                "prefill", clock, clock, track=track,
+                cat="prefill", parent=root, **pargs)
 
     def _materialize_tokens(self, rid: int, memo: Dict[int, np.ndarray]
                             ) -> List[int]:
@@ -761,8 +792,9 @@ class ContinuousScheduler:
         for arr, col, take in self._tokens[rid]:
             a = memo.get(id(arr))
             if a is None:
-                # repro: allow(no-host-sync-in-scan): the one place token
-                a = memo[id(arr)] = np.asarray(arr)  # data reaches the host
+                with phase("sched_readback"):
+                    # repro: allow(no-host-sync-in-scan): the one place token
+                    a = memo[id(arr)] = np.asarray(arr)  # data reaches host
             if a.ndim == 1:  # admission group token vector
                 out.append(int(a[col]))
             else:            # burst output (n, capacity)
@@ -777,8 +809,15 @@ class ContinuousScheduler:
                 if self._remaining[self.pool.slot_req[i].rid] == 0]
         if not done:
             return 0
-        # repro: allow(no-host-sync-in-scan): one small per-EVENT transfer
-        slot_host = jax.device_get(self.pool.slot_acc)
+        with phase("sched_complete", requests=len(done)):
+            self._retire(done, clock)
+        return len(done)
+
+    def _retire(self, done: List[int], clock: int) -> None:
+        """Report the finished requests of slots ``done`` and free them."""
+        with phase("sched_readback"):
+            # repro: allow(no-host-sync-in-scan): one per-EVENT transfer
+            slot_host = jax.device_get(self.pool.slot_acc)
         memo: Dict[int, np.ndarray] = {}
         for i in done:
             r = self.pool.slot_req[i]
@@ -821,7 +860,6 @@ class ContinuousScheduler:
             del self._tokens[r.rid]
             del self._remaining[r.rid], self._admitted[r.rid]
         self.pool.release(done)
-        return len(done)
 
     # ----------------------------------------------------------------- run
     def run(self, requests: Sequence[Request],
@@ -920,8 +958,9 @@ class ContinuousScheduler:
                     break
             if not pool.busy():
                 if self.tele is not None:
-                    self.tele.event(clock,
-                                    **self._event_gauges(clock, pending))
+                    with phase("sched_telemetry"):
+                        self.tele.event(
+                            clock, **self._event_gauges(clock, pending))
                 continue
             # burst until the next scheduler event: earliest completion,
             # next arrival, or the optional compile-bounding cap
@@ -947,27 +986,30 @@ class ContinuousScheduler:
             # energy delta is computed lazily against it (no sync)
             e_before = (self._acc_decode.energy_pj
                         if self.tele is not None else None)
-            active = pool.active_mask()
-            vectors = eng.vectors_for_floor(self._floor())
-            if eng.wear:
-                rvec = self._retention_vectors(clock)
-                (pool.tok, pool.cache, pool.pos, key, self._acc_decode,
-                 pool.slot_acc, self.life, toks) = eng._burst(
-                    eng.params, pool.tok, pool.cache, pool.pos, key,
-                    self._acc_decode, pool.slot_acc, active, vectors,
-                    self.life, rvec, self.addr.shifts, n=n)
-            elif self.life is not None:
-                rvec = self._retention_vectors(clock)
-                (pool.tok, pool.cache, pool.pos, key, self._acc_decode,
-                 pool.slot_acc, self.life, toks) = eng._burst(
-                    eng.params, pool.tok, pool.cache, pool.pos, key,
-                    self._acc_decode, pool.slot_acc, active, vectors,
-                    self.life, rvec, n=n)
-            else:
-                (pool.tok, pool.cache, pool.pos, key, self._acc_decode,
-                 pool.slot_acc, toks) = eng._burst(
-                    eng.params, pool.tok, pool.cache, pool.pos, key,
-                    self._acc_decode, pool.slot_acc, active, vectors, n=n)
+            with phase("sched_dispatch", steps=n,
+                       active=len(active_ids)) as dispatch:
+                active = pool.active_mask()
+                vectors = eng.vectors_for_floor(self._floor())
+                if eng.wear:
+                    rvec = self._retention_vectors(clock)
+                    (pool.tok, pool.cache, pool.pos, key, self._acc_decode,
+                     pool.slot_acc, self.life, toks) = eng._burst(
+                        eng.params, pool.tok, pool.cache, pool.pos, key,
+                        self._acc_decode, pool.slot_acc, active, vectors,
+                        self.life, rvec, self.addr.shifts, n=n)
+                elif self.life is not None:
+                    rvec = self._retention_vectors(clock)
+                    (pool.tok, pool.cache, pool.pos, key, self._acc_decode,
+                     pool.slot_acc, self.life, toks) = eng._burst(
+                        eng.params, pool.tok, pool.cache, pool.pos, key,
+                        self._acc_decode, pool.slot_acc, active, vectors,
+                        self.life, rvec, n=n)
+                else:
+                    (pool.tok, pool.cache, pool.pos, key, self._acc_decode,
+                     pool.slot_acc, toks) = eng._burst(
+                        eng.params, pool.tok, pool.cache, pool.pos, key,
+                        self._acc_decode, pool.slot_acc, active, vectors,
+                        n=n)
             for i in active_ids:  # lazy (n, capacity) fragment — no sync
                 rid = pool.slot_req[i].rid
                 take = min(n, self._remaining[rid])
@@ -977,34 +1019,37 @@ class ContinuousScheduler:
             decode_steps += n
             bursts += 1
             if self.tele is not None:
-                ins = self.tele.instruments
-                ins.inc("serve_bursts_total")
-                ins.inc("serve_decode_steps_total", n)
-                ins.observe("serve_burst_steps", n)
-                # Lazy derivations: the delta/split arithmetic runs on
-                # host floats at finalize — the burst path records two
-                # array refs and dispatches nothing
-                e_after = self._acc_decode.energy_pj
-                burst_e = Lazy(lambda a, b: a - b, e_after, e_before)
-                share = Lazy(lambda a, b, k=len(active_ids): (a - b) / k,
-                             e_after, e_before)
-                self.tele.tracer.complete(
-                    "burst", clock - n, clock, track="pool",
-                    cat="decode", steps=n, active=len(active_ids),
-                    energy_pj=burst_e)
-                for i in active_ids:
-                    rid = pool.slot_req[i].rid
+                with phase("sched_telemetry"):
+                    ins = self.tele.instruments
+                    ins.inc("serve_bursts_total")
+                    ins.inc("serve_decode_steps_total", n)
+                    ins.observe("serve_burst_steps", n)
+                    # Lazy derivations: the delta/split arithmetic runs on
+                    # host floats at finalize — the burst path records two
+                    # array refs and dispatches nothing
+                    e_after = self._acc_decode.energy_pj
+                    burst_e = Lazy(lambda a, b: a - b, e_after, e_before)
+                    share = Lazy(lambda a, b, k=len(active_ids): (a - b) / k,
+                                 e_after, e_before)
                     self.tele.tracer.complete(
-                        "decode", clock - n, clock,
-                        track=self._req_track(rid), cat="decode",
-                        parent=self._req_span.get(rid),
-                        steps=n, energy_pj=share)
+                        "burst", clock - n, clock, track="pool",
+                        cat="decode", steps=n, active=len(active_ids),
+                        energy_pj=burst_e, t0_ns=dispatch.t0_ns,
+                        t1_ns=dispatch.t1_ns)
+                    for i in active_ids:
+                        rid = pool.slot_req[i].rid
+                        self.tele.tracer.complete(
+                            "decode", clock - n, clock,
+                            track=self._req_track(rid), cat="decode",
+                            parent=self._req_span.get(rid),
+                            steps=n, energy_pj=share)
             self._complete(clock)
             self._maybe_scrub(clock, key)
             self._maybe_wear_check(clock)
             if self.tele is not None:
-                self.tele.event(clock,
-                                **self._event_gauges(clock, pending))
+                with phase("sched_telemetry"):
+                    self.tele.event(
+                        clock, **self._event_gauges(clock, pending))
 
         # ----- aggregate ledger: ONE final device->host sync covering the
         # stream accumulators AND the lifetime/wear counters (bits_total

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 from repro.core.energy_model import zero_slot_stats
 from repro.memory import WriteStats
 from repro.serve.engine import BATCH_AXIS
+from repro.telemetry.scopes import scope
 
 
 @jax.jit
@@ -50,21 +51,23 @@ def _admission_update(cache: Any, tok: jax.Array, pos: jax.Array,
     prefill-stream accumulator. Eager ``.at[].set`` dispatches here used to
     dominate the scheduler's event cost — keep any new per-admission
     device math inside this jit."""
-    cache = jax.tree.map(
-        lambda a, r: jnp.moveaxis(
-            jnp.moveaxis(a, BATCH_AXIS, 0).at[idx].set(
-                jnp.moveaxis(r, BATCH_AXIS, 0)), 0, BATCH_AXIS),
-        cache, rows)
-    tok = tok.at[idx].set(tok_new)
-    pos = pos.at[idx].set(pos_new)
-    admitted = jnp.zeros(tok.shape, bool).at[idx].set(True)
-    m = float(idx.shape[0])
-    share = {"energy_pj": acc.energy_pj / m,
-             "flips": (acc.flips01 + acc.flips10).astype(jnp.float32) / m,
-             "errors": acc.errors.astype(jnp.float32) / m}
-    slot_acc = {k: jnp.where(admitted, share[k], v)
-                for k, v in slot_acc.items()}
-    acc_prefill = acc_prefill + acc
+    with scope("admit_update"):
+        cache = jax.tree.map(
+            lambda a, r: jnp.moveaxis(
+                jnp.moveaxis(a, BATCH_AXIS, 0).at[idx].set(
+                    jnp.moveaxis(r, BATCH_AXIS, 0)), 0, BATCH_AXIS),
+            cache, rows)
+        tok = tok.at[idx].set(tok_new)
+        pos = pos.at[idx].set(pos_new)
+        admitted = jnp.zeros(tok.shape, bool).at[idx].set(True)
+        m = float(idx.shape[0])
+        share = {"energy_pj": acc.energy_pj / m,
+                 "flips": (acc.flips01 + acc.flips10).astype(jnp.float32)
+                 / m,
+                 "errors": acc.errors.astype(jnp.float32) / m}
+        slot_acc = {k: jnp.where(admitted, share[k], v)
+                    for k, v in slot_acc.items()}
+        acc_prefill = acc_prefill + acc
     return cache, tok, pos, slot_acc, acc_prefill
 
 

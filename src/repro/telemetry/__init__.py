@@ -13,8 +13,10 @@ Telemetry is strictly additive: with ``telemetry=None`` (the default
 everywhere) no instrument, span or drain exists and every run is
 bit-identical to the pre-telemetry code path; with it on, the compiled
 computations and the RNG key schedule are untouched, so tokens and
-WriteStats stay bit-identical too (asserted in tests and the
-``telemetry_overhead`` benchmark).
+WriteStats stay bit-identical too (asserted in tests).
+
+``scopes`` declares the named device scopes and host phases that put the
+serving stack's layers on a ``jax.profiler`` trace's clock.
 """
 from __future__ import annotations
 

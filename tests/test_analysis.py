@@ -252,6 +252,41 @@ class TestMetricsDiscipline:
         assert "metrics-discipline" not in rules_of(rep)
         assert any(w.rule == "metrics-discipline" for w in rep.waived)
 
+    def test_fires_on_bare_trace_marks_outside_telemetry(self, tmp_path):
+        rep = lint(tmp_path, {"src/repro/serve/mod.py": """
+            import jax
+            from jax.profiler import TraceAnnotation
+
+            def step(x):
+                with jax.named_scope("decode"):
+                    return x + 1
+
+            def host():
+                with jax.profiler.TraceAnnotation("burst"):
+                    pass
+        """})
+        v = [f for f in rep.violations if f.rule == "metrics-discipline"]
+        assert len(v) == 3
+        assert all("repro.telemetry.scopes" in f.message for f in v)
+
+    def test_silent_on_trace_marks_inside_telemetry(self, tmp_path):
+        rep = lint(tmp_path, {"src/repro/telemetry/scopes.py": """
+            import jax
+
+            def scope(name):
+                return jax.named_scope(name)
+
+            class Phase(jax.profiler.TraceAnnotation):
+                pass
+        """, "src/repro/serve/mod.py": """
+            from repro.telemetry.scopes import phase, scope
+
+            def step(x):
+                with scope("decode_model"):
+                    return x + 1
+        """})
+        assert "metrics-discipline" not in rules_of(rep)
+
 
 class TestRngStreamHygiene:
     def test_fires_on_magic_constant_and_offset_assign(self, tmp_path):

@@ -142,8 +142,22 @@ class TestPeaks:
                 hw.device_peaks()
 
 
+KEY_FLAGS = ("jax_compilation_cache_include_metadata_in_key",
+             "jax_hlo_source_file_canonicalization_regex")
+
+
+@pytest.fixture
+def key_flags():
+    """Restores the cache-key flags ``use_compile_cache`` sets."""
+    was = {k: getattr(jax.config, k) for k in KEY_FLAGS}
+    yield
+    for k, v in was.items():
+        jax.config.update(k, v)
+
+
 class TestCompileCache:
-    def test_fixed_path_in_the_checkout_is_ignored_by_git(self, monkeypatch):
+    def test_fixed_path_in_the_checkout_is_ignored_by_git(self, monkeypatch,
+                                                          key_flags):
         from pathlib import Path
         from repro.launch import compile_cache as cc
         monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
@@ -158,9 +172,48 @@ class TestCompileCache:
         ignored = (repo / ".gitignore").read_text().split()
         assert f"{cc.CACHE_DIR.name}/" in ignored
 
-    def test_env_directory_is_left_to_jax(self, monkeypatch, tmp_path):
+    def test_env_directory_is_left_to_jax(self, monkeypatch, tmp_path,
+                                          key_flags):
         from repro.launch import compile_cache as cc
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
         was = jax.config.jax_compilation_cache_dir
         assert cc.use_compile_cache() == str(tmp_path)
         assert jax.config.jax_compilation_cache_dir == was
+
+    def test_cache_key_keeps_scopes_without_the_checkout_path(
+            self, monkeypatch, tmp_path, key_flags):
+        """A program with a device scope never loads an executable
+        compiled without it (JAX's default key strips op metadata), and
+        the key does not depend on where the checkout lies."""
+        import hashlib
+
+        import jax.numpy as jnp
+        from jax._src import cache_key
+        from repro.launch import compile_cache as cc
+        from repro.telemetry.scopes import scope
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        cc.use_compile_cache()
+
+        def step(with_scope):
+            def f(x):
+                if not with_scope:
+                    return x * 2
+                with scope("decode_model"):
+                    return x * 2
+            return f
+
+        x = jnp.ones(4)
+        hlo = jax.jit(step(True)).lower(x).as_text(debug_info=True)
+        assert "decode_model" in hlo
+        assert str(cc.CHECKOUT) not in hlo
+        assert "tests/test_launch_tools.py" in hlo
+
+        def key(fn):
+            h = hashlib.sha256()
+            mod = jax.jit(fn).lower(x).compiler_ir("stablehlo")
+            cache_key._hash_computation(h, mod, cache_key.IgnoreCallbacks.NO)
+            return h.hexdigest()
+        assert key(step(False)) != key(step(True))
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          False)
+        assert key(step(False)) == key(step(True))
