@@ -113,6 +113,28 @@ def _stuck_gate(old, new, worn):
     return jnp.where(worn, old, new), lost
 
 
+def _ring_column(leaf, col, ax: int, batch_axis: int):
+    """Ring column ``col[b]`` of every slot ``b``: ``leaf`` with axis
+    ``ax`` cut to 1, in the leaf's axis order.
+
+    A bitwise OR over the ring axis of the words' unsigned view, every
+    word but the slot's column masked to 0: one streaming read of the
+    leaf, bit-exact (a float sum would lose -0.0 and NaN payloads). A
+    gather would address the column one element per index, or, batched
+    over the slots, make XLA relayout the whole leaf inside the decode
+    scan; this keeps the leaf's layout and, over a slot mesh, needs no
+    collective."""
+    ut = uint_type(leaf.dtype)
+    ishape = [1] * leaf.ndim
+    ishape[batch_axis] = col.shape[0]
+    hit = (jax.lax.broadcasted_iota(jnp.int32, leaf.shape, ax)
+           == col.reshape(ishape))
+    words = jnp.where(hit, jax.lax.bitcast_convert_type(leaf, ut), ut(0))
+    word = jax.lax.reduce(words, ut(0), jax.lax.bitwise_or, (ax,))
+    return jax.lax.bitcast_convert_type(jnp.expand_dims(word, ax),
+                                        leaf.dtype)
+
+
 def _soft_error_hook(key, x, ber: float, hardened: bool):
     """Post-write retention upsets + the strike count (popcount of the
     flipped-bit mask)."""
@@ -415,8 +437,10 @@ class WritePlan:
         ``batch_axis``); other approximate leaves fall back to the full
         diff. Flip/energy stats are identical to ``write`` — the rest of
         the tree is bit-unchanged after a decode step, so CMP contributes
-        exactly zero there — but the per-step cost drops from O(cache) to
-        O(token) lane work. ``pos`` is the (B,) position vector.
+        exactly zero there — but the per-step lane work drops from
+        O(cache) to O(token). Reading the column (``_ring_column``) and
+        storing it back are one streaming pass over each leaf apiece.
+        ``pos`` is the (B,) position vector.
 
         ``addr``: optional ``(shifts, worn)`` physical-addressing operands
         (see ``write``). The written column's *address* maps through the
@@ -459,12 +483,11 @@ class WritePlan:
             C = o.shape[ax]
             ishape = [1] * o.ndim
             ishape[self.batch_axis] = pos.shape[0]
-            idx = (pos % C).reshape(ishape)
-            gshape = o.shape[:ax] + (1,) + o.shape[ax + 1:]
-            idx_g = jnp.broadcast_to(idx, gshape)
+            col = pos % C
+            idx = col.reshape(ishape)
             with scope("extent_gather"):
-                o_col = jnp.take_along_axis(o, idx_g, axis=ax)
-                n_col = jnp.take_along_axis(n, idx_g, axis=ax)
+                o_col = _ring_column(o, col, ax, self.batch_axis)
+                n_col = _ring_column(n, col, ax, self.batch_axis)
             if alias_cols is not None:
                 keep = (pos < alias_cols).reshape(ishape)
                 n_col = jnp.where(keep, o_col, n_col)

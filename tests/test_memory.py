@@ -2,6 +2,7 @@
 registry, MemoryRegion, the ApproxStore deprecation shim, the soft-error
 hook, ExtentTable.reset_stats, and the compression wire path."""
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -287,3 +288,140 @@ class TestServeBackendSelection:
         assert a["bits_total"] == b["bits_total"]
         np.testing.assert_allclose(a["energy_pj"], b["energy_pj"],
                                    rtol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# the column write's column addressing (WritePlan.write_columns)
+# ---------------------------------------------------------------------------
+
+_KV_AXES = ("layers", "batch", "kv_seq", "kv_heads", "head_dim")
+#: qwen2.5-3b-like (2 KV heads of 128) and h2o-danube-1.8b-like (8 of 80)
+_COLUMN_SHAPES = {"qwen": (3, 4, 16, 2, 128), "danube": (2, 4, 16, 8, 80)}
+#: each cell's decode pool: 32 slots x 512 positions at full depth
+_CELL_SHAPES = {"qwen2.5-3b": (36, 32, 512, 2, 128),
+                "h2o-danube-1.8b": (24, 32, 512, 8, 80)}
+
+
+def _element_column(leaf, col, ax, batch_axis):
+    """The column as one index per element (``take_along_axis`` over an
+    index broadcast to the whole column): the reference the column write
+    must match bit for bit."""
+    ishape = [1] * leaf.ndim
+    ishape[batch_axis] = col.shape[0]
+    gshape = leaf.shape[:ax] + (1,) + leaf.shape[ax + 1:]
+    return jnp.take_along_axis(
+        leaf, jnp.broadcast_to(col.reshape(ishape), gshape), axis=ax)
+
+
+def _kv_plan(tree, backend="lanes_ref", spec=None):
+    return memory.WritePlan.for_tree(
+        tree, policy=kv_cache_policy, backend=backend,
+        axes={"k": _KV_AXES, "v": _KV_AXES}, address_spec=spec)
+
+
+def _bits16(tree):
+    return [np.asarray(jax.lax.bitcast_convert_type(a, jnp.uint16))
+            for a in jax.tree.leaves(tree)]
+
+
+def _elementwise_ring_gathers(text, rank):
+    """Gathers in a lowered (StableHLO) or compiled (HLO) module that read
+    a rank-``rank`` operand one element per index: all-ones
+    ``slice_sizes``."""
+    sizes = (re.findall(r"slice_sizes = array<i64: ([\d, ]+)>", text)
+             + re.findall(r"slice_sizes=\{([\d,]+)\}", text))
+    return [s for s in sizes
+            if [int(x) for x in s.replace(" ", "").split(",")]
+            == [1] * rank]
+
+
+class TestColumnWrite:
+    @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+    @pytest.mark.parametrize("shape,ax,batch_axis", [
+        ((3, 4, 16, 2, 8), 2, 1), ((16, 4, 3, 8), 0, 1),
+        ((4, 3, 16), 2, 0), ((3, 16, 4, 8), 1, 2)])
+    def test_ring_column_is_the_element_gather(self, shape, ax,
+                                               batch_axis, dtype):
+        """Any ring and slot axis, any word width, and the words a float
+        sum or move would alter: -0.0, NaNs with payloads, subnormals."""
+        from repro.core.priority import uint_type
+        from repro.memory.plan import _ring_column
+        ut = uint_type(dtype)
+        words = jax.random.bits(jax.random.PRNGKey(0), shape, ut)
+        sign = ut(1 << (8 * jnp.dtype(ut).itemsize - 1))
+        words = words.at[(0,) * len(shape)].set(sign)   # -0.0
+        leaf = jax.lax.bitcast_convert_type(words, dtype)
+        col = jax.random.randint(jax.random.PRNGKey(1),
+                                 (shape[batch_axis],), 0, shape[ax])
+        col = col.at[0].set(0)
+        got = _ring_column(leaf, col, ax, batch_axis)
+        # gathered as integers: a float gather may quiet a NaN (the CPU
+        # backend moves bf16 through f32)
+        want = _element_column(words, col, ax, batch_axis)
+        assert got.shape == want.shape
+        np.testing.assert_array_equal(
+            np.asarray(jax.lax.bitcast_convert_type(got, ut)),
+            np.asarray(want))
+
+    @pytest.mark.parametrize("arm", ["plain", "alias", "worn"])
+    @pytest.mark.parametrize("backend", ["lanes_ref", "pallas"])
+    @pytest.mark.parametrize("name", sorted(_COLUMN_SHAPES))
+    def test_matches_the_element_gather(self, monkeypatch, name, backend,
+                                        arm):
+        """Stored bits and every WriteStats field equal the element-gather
+        column write's: ring wrap (pos >= C), inactive slots (new rows
+        equal to old, as ``mask_rows`` leaves them), a prefix alias, and
+        one worn row group."""
+        from repro.memory import plan as plan_mod
+        from repro.memory.address import AddressSpec
+        shape = _COLUMN_SHAPES[name]
+        ks = jax.random.split(jax.random.PRNGKey(5), 3)
+        old = {n: jax.random.normal(k, shape).astype(jnp.bfloat16)
+               for n, k in zip("kv", ks[:2])}
+        active = jnp.asarray([True, False, True, True])
+        new = jax.tree.map(lambda o: jnp.where(
+            active[None, :, None, None, None],
+            jax.random.normal(ks[2], shape).astype(jnp.bfloat16), o), old)
+        pos = jnp.asarray([3, 9, 16, 37], jnp.int32)   # 16, 37 wrap
+        spec = AddressSpec(group_cols=4) if arm == "worn" else None
+        plan = _kv_plan(old, backend, spec)
+        kw = {}
+        if arm == "alias":
+            kw["alias_cols"] = jnp.asarray([5, 0, 20, 0], jnp.int32)
+        if arm == "worn":
+            # slot 3's column 37 % 16 = 5 lies in its row group 1 of 4
+            worn = jnp.zeros((2, spec.n_groups(shape, 2, 1)), bool)
+            kw["addr"] = (plan.identity_address().shifts,
+                          worn.at[:, 3 * 4 + 1].set(True))
+        key = jax.random.PRNGKey(9)
+        got, st = plan.write_columns(key, old, new, pos, **kw)
+        monkeypatch.setattr(plan_mod, "_ring_column", _element_column)
+        want, st_ref = plan.write_columns(key, old, new, pos, **kw)
+        for a, b in zip(_bits16(got), _bits16(want)):
+            np.testing.assert_array_equal(a, b)
+        assert st.host_dict() == st_ref.host_dict()
+        if arm == "worn":
+            assert st.errors > 0
+
+    @pytest.mark.parametrize("cell", sorted(_CELL_SHAPES))
+    def test_reads_no_element_gather_at_cell_shapes(self, monkeypatch,
+                                                    cell):
+        """Lowered at each cell's pool shape (abstract inputs, nothing
+        allocated), the column write reads no ring leaf one element per
+        index; the element gather it replaced does."""
+        from repro.memory import plan as plan_mod
+        shape = _CELL_SHAPES[cell]
+        leaf = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+        tree = {"k": leaf, "v": leaf}
+        plan = _kv_plan(tree)
+        vec = plan.vectors_for()
+        args = (jax.ShapeDtypeStruct((2,), jnp.uint32), tree, tree,
+                jax.ShapeDtypeStruct((shape[1],), jnp.int32))
+
+        def lowered():
+            return jax.jit(lambda k, o, n, p: plan.write_columns(
+                k, o, n, p, vec)).lower(*args).as_text()
+
+        assert _elementwise_ring_gathers(lowered(), len(shape)) == []
+        monkeypatch.setattr(plan_mod, "_ring_column", _element_column)
+        assert _elementwise_ring_gathers(lowered(), len(shape))

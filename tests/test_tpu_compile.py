@@ -15,7 +15,9 @@ installed TPU compiler a *described* v5e and compile the kernels with
     v5e:2x2, mapped over a 4-slot pool's slot axis as the die-sharded
     scheduler runs them: they compile, and the column write gathers
     nothing across chips;
-  * the sliding-window attention kernel at gemma2-9b's local layers.
+  * the sliding-window attention kernel at gemma2-9b's local layers;
+  * the decode column write at both benchmark cells' pool shapes, which
+    must read the written column without an element-wise gather.
 
 Nothing runs: a compile that passes is not a chip run. The topology is
 described inside a fixture, never at import, so every test worker
@@ -197,6 +199,50 @@ def test_sharded_column_write_compiles_for_four_chips(four_chip_plan):
     n = _collectives(text)
     assert n["all-gather"] == 0, n
     assert n["all-reduce"] >= 1, n
+
+
+#: each cell's decode pool: 32 slots x 512 positions at full depth
+CELL_POOLS = {"qwen2.5-3b": (36, 32, 512, 2, 128),
+              "h2o-danube-1.8b": (24, 32, 512, 8, 80)}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_POOLS))
+def test_column_write_reads_no_element_gather_for_v5e(one_chip, monkeypatch,
+                                                     cell):
+    """Compiled for one v5e at each cell's pool shape, the decode column
+    write reads no ring leaf one element per index (all-ones
+    ``slice_sizes``): such a gather costs ~7 ms per K or V leaf on the
+    chip, where the column is under 1 MB. The element gather it replaced
+    shows there."""
+    from repro.core.priority import kv_cache_policy
+    from repro.memory import WritePlan
+    from repro.memory import plan as plan_mod
+    shape = CELL_POOLS[cell]
+    axes = ("layers", "batch", "kv_seq", "kv_heads", "head_dim")
+    leaf = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    tree = {"k": leaf, "v": leaf}
+    plan = WritePlan.for_tree(tree, policy=kv_cache_policy,
+                              axes={"k": axes, "v": axes})
+    vec = plan.vectors_for()
+    args = (jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip),
+            tree, tree,
+            jax.ShapeDtypeStruct((shape[1],), jnp.int32, sharding=one_chip))
+    ones = "slice_sizes={%s}" % ",".join(["1"] * len(shape))
+
+    def text():
+        return _native_text(
+            lambda k, o, n, p: plan.write_columns(k, o, n, p, vec), args)
+
+    assert ones not in text()
+
+    def element_column(leaf, col, ax, batch_axis):
+        idx = col.reshape([-1 if d == batch_axis else 1
+                           for d in range(leaf.ndim)])
+        return jnp.take_along_axis(leaf, jnp.broadcast_to(
+            idx, leaf.shape[:ax] + (1,) + leaf.shape[ax + 1:]), axis=ax)
+
+    monkeypatch.setattr(plan_mod, "_ring_column", element_column)
+    assert ones in text()
 
 
 def test_sharded_scrub_compiles_for_four_chips(four_chip_plan):
