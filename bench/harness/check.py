@@ -16,15 +16,17 @@ that its limits file, ``bench/limits/<cell>.json``, lists:
   which the served token's reference logit lies below the reference's best
   (greedy serving picks the best). Covers the model step: the prefill, and
   each decode step over the K/V the store held.
-* ``kv_exp_mismatch``: the share of stored K/V elements of the sample whose
-  sign and exponent bits differ from the reference's own K/V rounded to
-  bf16. The EXTENT write keeps sign and exponent exact and approximates the
-  mantissa, so a store that holds the new values differs only where
-  rounding crosses a power of two; a write that keeps old bits, or a step
-  that leaves the cache as it was, differs almost everywhere.
+* ``kv_exp_mismatch``: the share of stored cache elements of the sample
+  (every leaf of every slot class) whose sign and exponent bits differ
+  from the reference's own values rounded to bf16. The EXTENT write keeps
+  sign and exponent exact and approximates the mantissa, so a store that
+  holds the new values differs only where rounding crosses a power of
+  two; a write that keeps old bits, or a step that leaves the cache as it
+  was, differs almost everywhere.
 * ``mantissa_loss_gap``: at the sample's decoded positions, the stored
-  mantissa bits that the write lost against the reference's V
-  (``references.extent``), to the losses its driver levels predict:
+  mantissa bits that the write lost against the reference's values of the
+  leaf its module names (``LOSS_LEAF``; ``references.extent``), to the
+  losses its driver levels predict:
   ``|lost / predicted - 1|``. An exact store loses none (1), a store of
   fewer mantissa bits loses many.
 * ``write_energy_gap``, ``write_error_gap``: the decode writes' energy and
@@ -35,12 +37,16 @@ that its limits file, ``bench/limits/<cell>.json``, lists:
   tokens they asked for, all inside the vocabulary (limit 0).
 
 With ``control``, the float8 reference takes the program's place: its
-first-ranked tokens for the served ones and its own K/V for the stored
+first-ranked tokens for the served ones and its own cache for the stored
 ones. The ledger and ``bad_requests`` stay the program's.
+
+Nothing here knows an architecture: the cache is the program's tree, each
+leaf ``(layer stack, slot, position, ...)`` in the pool, and what a leaf's
+bits mean is the reference module's (``references/__init__.py``).
 """
 from __future__ import annotations
 
-import importlib
+from types import ModuleType
 from typing import Any, Dict, List, Mapping, Sequence
 
 import jax
@@ -82,11 +88,10 @@ def pick(report: Mapping[str, Any], n: int, seed: int) -> List[int]:
 def extract(cache: Mapping[str, Any], report: Mapping[str, Any],
             rids: Sequence[int], prompts: Mapping[int, np.ndarray]
             ) -> List[Dict[str, Any]]:
-    """Copy each sampled request's stored K/V rows out of the pool, with
-    its tokens, before the program is freed. Positions past the request's
-    own are left in the copy and never read."""
-    if set(cache) != {"slot0"}:
-        raise ValueError(f"one cache slot class expected, got {sorted(cache)}")
+    """Copy each sampled request's row of every cache leaf out of the pool
+    (``stored``, in the pool's tree), with its tokens, before the program
+    is freed. Positions past the request's own are left in the copy and
+    never read."""
     out = []
     for rid in rids:
         r = report["requests"][rid]
@@ -96,8 +101,7 @@ def extract(cache: Mapping[str, Any], report: Mapping[str, Any],
         out.append({
             "rid": rid, "prompt_len": int(p.shape[-1]), "served": served,
             "tokens": np.concatenate([p.reshape(-1), served[:-1]]),
-            "k": jnp.array(cache["slot0"]["k"][:, s]),
-            "v": jnp.array(cache["slot0"]["v"][:, s])})
+            "stored": jax.tree.map(lambda a: jnp.array(a[:, s]), cache)})
     return out
 
 
@@ -130,46 +134,60 @@ def decode_ledger(report: Mapping[str, Any],
                            for r in report["requests"].values())}
 
 
-def compare(params: Any, cfg: Mapping[str, Any], reference: str,
+def _keys(path) -> List[str]:
+    return [str(getattr(k, "key", k)) for k in path]
+
+
+def _rows(leaf: jax.Array, keep: jax.Array) -> jax.Array:
+    """``keep`` over a leaf's positions (axis 1), broadcast to its rank."""
+    return keep[:leaf.shape[1]].reshape((1, -1) + (1,) * (leaf.ndim - 2))
+
+
+def compare(params: Any, cfg: Mapping[str, Any], ref: ModuleType,
             samples: Sequence[Mapping[str, Any]], max_seq: int,
             ledger: Mapping[str, float], control: bool = False
             ) -> List[Dict[str, float]]:
-    """Replay every sample through the reference, over its whole row of
-    ``max_seq`` positions so one compiled program serves every request
-    (rows past the request's own are computed and not read). Returns the
-    program's numbers (every one of ``NUMBERS`` but ``bad_requests``) and,
-    with ``control``, the float8 control's after them."""
+    """Replay every sample through the reference module ``ref``, over its
+    whole row of ``max_seq`` positions so one compiled program serves every
+    request (rows past the request's own are computed and not read).
+    Returns the program's numbers (every one of ``NUMBERS`` but
+    ``bad_requests``) and, with ``control``, the float8 control's after
+    them."""
     from references import extent
-    ref = importlib.import_module(f"references.{reference}")
     frozen = dict(cfg)
 
-    def tally(out, target, k, v, P, T):
+    def tally(out, target, stored, P, T):
         rows = jnp.arange(target.shape[0])
         valid = (rows >= P - 1) & (rows < T)
-        held = (rows < T)[None, :, None, None]
-        decoded = ((rows >= P) & (rows < T))[None, :, None, None]
+        held, decoded = rows < T, (rows >= P) & (rows < T)
         lg = out["logits"]
         got = jnp.take_along_axis(lg, target[:, None], -1)[:, 0]
         res = {"gap": jnp.max(jnp.where(valid, jnp.max(lg, -1) - got, 0.0)),
-               "mism": sum(jnp.sum((_top9(own) != _top9(st)) & held)
-                           for own, st in ((out["k"], k), (out["v"], v))),
-               "elems": 2 * jnp.sum(held) * k[:, 0].size,
-               "columns": jnp.sum(decoded)}
-        res.update(extent.mantissa_losses(out["v"], v, decoded))
-        for leaf, st in (("k", k), ("v", v)):
-            for name, x in extent.column_expectation(leaf, st,
-                                                     decoded).items():
-                res[f"{leaf}_{name}"] = x
+               "mism": 0, "elems": 0, "columns": jnp.sum(decoded)}
+        flat, tree = jax.tree_util.tree_flatten_with_path(stored)
+        for (path, st), ow in zip(flat, tree.flatten_up_to(out["own"])):
+            keys = _keys(path)
+            name, key = keys[-1], "/".join(keys)
+            h, d = _rows(st, held), _rows(st, decoded)
+            res["mism"] += jnp.sum((_top9(ow) != _top9(st)) & h)
+            res["elems"] += jnp.sum(h) * st[:, 0].size
+            planes = ref.PLANES[name]
+            for k, x in extent.column_expectation(planes, st, d).items():
+                res[f"{k}@{key}"] = x
+            if name == ref.LOSS_LEAF:
+                for k, x in extent.mantissa_losses(
+                        ow, st, d, planes, ref.LOSS_PLANES).items():
+                    res[f"{k}@{key}"] = x
         return res
 
     @jax.jit
-    def replay(params, toks, P, T, target, k, v):
-        out = ref.served_logits(params, frozen, toks, P, k, v, "f32")
-        res = [tally(out, target, k, v, P, T)]
+    def replay(params, toks, P, T, target, stored):
+        out = ref.served_logits(params, frozen, toks, P, stored, "f32")
+        res = [tally(out, target, stored, P, T)]
         if control:
-            c = ref.served_logits(params, frozen, toks, P, k, v, "fp8")
-            res.append(tally(out, jnp.argmax(c["logits"], -1), c["k"],
-                             c["v"], P, T))
+            c = ref.served_logits(params, frozen, toks, P, stored, "fp8")
+            res.append(tally(out, jnp.argmax(c["logits"], -1), c["own"],
+                             P, T))
         return res
 
     sums: List[Dict[str, float]] = [{} for _ in range(1 + control)]
@@ -182,7 +200,7 @@ def compare(params: Any, cfg: Mapping[str, Any], reference: str,
         toks[:T] = s["tokens"]
         rs = jax.device_get(replay(
             params, jnp.asarray(toks), jnp.int32(P), jnp.int32(T),
-            jnp.asarray(target), s["k"], s["v"]))
+            jnp.asarray(target), s["stored"]))
         for acc, r in zip(sums, rs):
             acc["gap"] = max(acc.get("gap", 0.0), float(r.pop("gap")))
             for name, x in r.items():
@@ -192,17 +210,18 @@ def compare(params: Any, cfg: Mapping[str, Any], reference: str,
 
 def _numbers(sums: Mapping[str, float], ledger: Mapping[str, float]
              ) -> Dict[str, float]:
-    both = lambda name: sums[f"k_{name}"] + sums[f"v_{name}"]
+    def leaves(name):  # summed over the leaves, in the tree's order
+        return sum(sums[k] for k in sorted(sums) if k.startswith(f"{name}@"))
     cols = max(sums["columns"], 1.0)
     per_col = lambda x, n: x / n if n else 0.0
     return {
         "logit_gap": sums["gap"],
         "kv_exp_mismatch": sums["mism"] / max(sums["elems"], 1.0),
-        "mantissa_loss_gap": _gap(sums["net"], sums["expected"]),
+        "mantissa_loss_gap": _gap(leaves("net"), leaves("expected")),
         "write_energy_gap": _gap(
             per_col(ledger["energy_pj"], ledger["columns"]),
-            both("energy_pj") / cols),
+            leaves("energy_pj") / cols),
         "write_error_gap": _gap(
             per_col(ledger["errors"], ledger["columns"]),
-            both("errors") / cols),
+            leaves("errors") / cols),
     }

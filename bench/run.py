@@ -30,6 +30,7 @@ import argparse  # noqa: E402
 import copy  # noqa: E402
 import dataclasses  # noqa: E402
 import gc  # noqa: E402
+import importlib  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
@@ -78,10 +79,29 @@ def metric_names(bench: Mapping[str, Any], cell: Mapping[str, Any],
 
 
 def model_config(sizes: Mapping[str, Any]):
+    """The program's ``ModelConfig`` of a configuration file's ``model``:
+    every list a tuple, as the frozen dataclass holds them."""
     from repro.configs.base import ModelConfig
-    d = dict(sizes)
-    d["window_pattern"] = tuple(d["window_pattern"])
-    return ModelConfig(**d)
+    return ModelConfig(**{k: tuple(v) if isinstance(v, list) else v
+                          for k, v in sizes.items()})
+
+
+def wrapping_rings(api, mix: traffic.Mix) -> List[str]:
+    """Cache leaves (by path) whose ring of positions (the ``kv_seq`` axis)
+    holds fewer than the mix's longest request. Such a ring wraps, and its
+    decode writes land on words the final store no longer holds, which
+    the comparison prices as writes over zeroed columns."""
+    import jax
+    longest = max(mix.prompt_lengths) + mix.decode_max
+    shapes = jax.eval_shape(lambda: api.init_cache(1, mix.max_seq))
+    axes = jax.tree_util.tree_leaves(
+        api.cache_axes(), is_leaf=lambda x: isinstance(x, tuple))
+    out = []
+    for (path, leaf), names in zip(
+            jax.tree_util.tree_flatten_with_path(shapes)[0], axes):
+        if "kv_seq" in names and leaf.shape[names.index("kv_seq")] < longest:
+            out.append(jax.tree_util.keystr(path))
+    return out
 
 
 def warm_up(sch, mix: traffic.Mix, vocab: int, make_request
@@ -183,8 +203,14 @@ def main(argv: Optional[List[str]] = None, rehearse: Any = None) -> int:
     from repro.serve.scheduler import Request
     from harness.weights import make_params
 
+    ref = importlib.import_module(f"references.{conf['reference']}")
     cfg = model_config(sizes)
-    params = make_params(get_model(cfg), sizes, args.seed)
+    api = get_model(cfg)
+    wraps = wrapping_rings(api, mix)
+    if wraps:
+        return fail(f"the mix's longest request wraps the ring of {wraps}; "
+                    "the comparison cannot price a wrapped ring")
+    params = make_params(api, sizes, args.seed, ref)
     t = phase("weights", t)
     eng = ServingEngine(cfg, ServeConfig(
         max_seq=mix.max_seq, max_new_tokens=mix.max_new_tokens,
@@ -249,14 +275,15 @@ def main(argv: Optional[List[str]] = None, rehearse: Any = None) -> int:
     del sch, eng, stream
     gc.collect()
     t_check = time.perf_counter()
-    found = check.compare(params, sizes, conf["reference"], samples,
-                          mix.max_seq, ledger, control=bool(args.control))
+    found = check.compare(params, sizes, ref, samples, mix.max_seq, ledger,
+                          control=bool(args.control))
     for n in found:
         n["bad_requests"] = bad
     nums = found[-1]  # the control's, where it takes the program's place
     check_s = time.perf_counter() - t_check
 
-    work = counts.window_work(sizes, served.values(), decode_steps, capacity)
+    work = counts.window_work(ref, sizes, served.values(), decode_steps,
+                              capacity)
     reduced = None
     if trace_dir:
         reduced = tr.reduce(tr.load(tr.find_xplane(trace_dir)))

@@ -25,8 +25,13 @@ LIMITS = {"logit_gap": 0.008, "kv_exp_mismatch": 0.06,
           "write_error_gap": 0.05, "bad_requests": 0}
 
 
+# two slot classes of cache (the window pattern's two positions), neither
+# ring wrapped: the window of 128 holds the whole row of 96
+TWO_CLASSES = [128, 0]
+
+
 def rehearse(capsys, fault=None, seed=3_000_000_019, control=0,
-             limits=LIMITS, rc=0):
+             limits=LIMITS, rc=0, window_pattern=None):
     f = FAULTS[fault] if fault else None
     mix = dict(capacity=8, max_seq=96, prompt_lengths=(8, 16),
                prompt_counts=(1, 1), decode_min=8, decode_max=64, block=8,
@@ -39,6 +44,8 @@ def rehearse(capsys, fault=None, seed=3_000_000_019, control=0,
                    head_dim=32, d_ff=256, vocab_size=4096),
         mix=mix, peaks={"bf16_flops": 1e12, "hbm_bytes_per_s": 1e11},
         limits=limits, patch=f.patch if f else None)
+    if window_pattern is not None:
+        r.model["window_pattern"] = window_pattern
     got = run.main(["--workload", CELL, "--seed", str(seed), "--seconds",
                     "1.5", "--trace", "0", "--control", str(control)],
                    rehearse=r)
@@ -79,3 +86,24 @@ def test_float8_control_is_not_correct(capsys, seed):
 def test_limits_naming_an_unknown_number_are_refused(capsys):
     out = rehearse(capsys, limits=dict(LIMITS, no_such_number=1.0), rc=1)
     assert out.out == "" and "no_such_number" in out.err
+
+
+def test_two_slot_classes_sound_run_is_correct(capsys):
+    out = rehearse(capsys, window_pattern=TWO_CLASSES)
+    assert out["correct"], out["checks"]
+    assert out["attempted"] > 10 and out["failed"] == 0
+
+
+@pytest.mark.parametrize("fault", sorted(FAULTS) + ["control"])
+def test_two_slot_classes_broken_or_control_is_not_correct(capsys, fault):
+    if fault == "control":
+        out = rehearse(capsys, control=1, window_pattern=TWO_CLASSES)
+    else:
+        out = rehearse(capsys, fault=fault, window_pattern=TWO_CLASSES)
+    assert not out["correct"], out["checks"]
+
+
+def test_a_ring_the_longest_request_would_wrap_is_refused(capsys):
+    # the longest request is 16 + 64 positions; a window of 32 keeps 32
+    out = rehearse(capsys, window_pattern=[32, 0], rc=1)
+    assert out.out == "" and "'slot0'" in out.err and "'slot1'" not in out.err

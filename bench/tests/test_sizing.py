@@ -4,6 +4,7 @@ whole pool and a one-request admission of the longest prompt. Their
 ``memory_analysis`` is the sizing of the cells (PERF.md, section 4): the
 weights, the pool and the larger of the two programs' working sets must fit
 one chip's 16 GB."""
+import importlib
 import json
 import os
 from pathlib import Path
@@ -13,7 +14,7 @@ import jax.numpy as jnp
 import pytest
 
 import run
-from harness import counts, traffic
+from harness import traffic
 
 ROOT = Path(__file__).resolve().parents[2]
 HBM = 16e9
@@ -44,8 +45,9 @@ def test_cell_fits_one_chip(one_chip, name):
 
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
     cell = run.cell_of(bench, name)
-    sizes = json.loads((ROOT / cell["config_entry"]["file"]).read_text())[
-        "model"]
+    conf = json.loads((ROOT / cell["config_entry"]["file"]).read_text())
+    sizes, ref = conf["model"], importlib.import_module(
+        f"references.{conf['reference']}")
     mix = traffic.Mix.load(ROOT / "bench" / "mixes" / f"{cell['traffic']}.json")
     cfg = run.model_config(sizes)
     api = get_model(cfg)
@@ -75,8 +77,10 @@ def test_cell_fits_one_chip(one_chip, name):
         params, {"tokens": i32(1, max(mix.prompt_lengths))}, rows, key,
         vec).compile().memory_analysis()
 
-    weights = counts.param_count(sizes) * 2
-    pool = C * S * counts.kv_bytes_per_position(sizes)
+    weights = ref.param_count(sizes) * 2
+    pool = ref.cache_bytes(sizes, C, S)
+    assert sum(x.size * x.dtype.itemsize
+               for x in jax.tree.leaves(cache)) == pool
     burst_peak = (burst.argument_size_in_bytes + burst.output_size_in_bytes
                   + burst.temp_size_in_bytes)
     admit_peak = (admit.argument_size_in_bytes + admit.temp_size_in_bytes
